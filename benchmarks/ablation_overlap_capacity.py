@@ -19,9 +19,7 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro import compat
 from repro.core import overlap as ov
 from repro.kernels.ref import moe_ffn_ref
 from repro.models import moe as moe_mod
@@ -34,7 +32,8 @@ def capacity_ablation() -> None:
                      n_heads=2, n_kv_heads=2, d_ff=0, vocab_size=64,
                      n_experts=8, top_k=2, moe_d_ff=16)
     p = moe_mod.init_moe(jax.random.PRNGKey(0), "m", cfg)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 32)) * 0.5
     ref = moe_ffn_ref(x.reshape(-1, 32), p["router"], p["wi"], p["wo"],
                       cfg.top_k).reshape(x.shape)
@@ -49,7 +48,7 @@ def capacity_ablation() -> None:
             return out, drop
 
         with mesh:
-            out, drop = ep_mod.shard_map(
+            out, drop = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(None, None), P(None, None),
                           P(None, None, None), P(None, None, None)),

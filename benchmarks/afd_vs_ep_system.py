@@ -16,12 +16,11 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro import configs
 from repro.api import Deployment
 from repro.models.model import make_model
-from repro.parallel.afd import AFDRuntime, split_nodes
+from repro.parallel.afd import AFDRuntime, role_devices
 
 ARCH = "granite-moe-1b-a400m"
 
@@ -48,12 +47,7 @@ def main() -> None:
     ep_logits = logits
 
     # --- AFD two-role path ---------------------------------------------------
-    devs = jax.devices()
-    if len(devs) >= 2:
-        half = len(devs) // 2
-        a_dev, f_dev = split_nodes(devs, half, len(devs) - half)
-    else:                       # 1-device container: colocated roles — the
-        a_dev = f_dev = [devs[0]]   # M2N cycle still runs structurally
+    a_dev, f_dev = role_devices(jax.devices())
 
     rt = AFDRuntime(cfg, params, a_dev, f_dev)
     caches, pos = rt.init_cache(B, 64)

@@ -29,7 +29,7 @@ from repro.fleet.controller import FleetController, FleetReplica
 from repro.fleet.events import FailureEvent
 from repro.fleet.rescaler import ElasticRescaler
 from repro.models.model import make_model
-from repro.parallel.afd import AFDRuntime, split_nodes
+from repro.parallel.afd import AFDRuntime, role_devices
 from repro.serving.afd_engine import AFDServeEngine, HFUProbe
 from repro.serving.workload import generate_trace, get_profile
 
@@ -46,12 +46,7 @@ def main() -> None:
     cfg = configs.get_smoke_config(ARCH)
     model = make_model(cfg)
     params = model.init(jax.random.PRNGKey(SEED))
-    devs = jax.devices()
-    if len(devs) >= 2:
-        half = len(devs) // 2
-        a_dev, f_dev = split_nodes(devs, half, len(devs) - half)
-    else:
-        a_dev = f_dev = [devs[0]]
+    a_dev, f_dev = role_devices(jax.devices())
 
     spec = registry.spec_from_arch_config(cfg)
     hw = registry.resolve_hardware("H800")

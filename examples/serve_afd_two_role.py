@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.models.model import make_model
-from repro.parallel.afd import AFDRuntime, split_nodes
+from repro.parallel.afd import AFDRuntime, role_devices
 
 
 def main() -> None:
@@ -21,12 +21,7 @@ def main() -> None:
     model = make_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
 
-    devs = jax.devices()
-    if len(devs) >= 2:
-        a_dev, f_dev = split_nodes(devs, len(devs) // 2,
-                                   len(devs) - len(devs) // 2)
-    else:
-        a_dev = f_dev = [devs[0]]
+    a_dev, f_dev = role_devices(jax.devices())
     print(f"A-role: {len(a_dev)} device(s); F-role: {len(f_dev)} device(s)")
 
     rt = AFDRuntime(cfg, params, a_dev, f_dev)

@@ -353,30 +353,27 @@ def cmd_serve_traffic(args) -> int:
 
     import jax                                     # lazy: jax-backed command
 
-    from repro import configs
     from repro.api import registry
     from repro.core import planner as pln
     from repro.core.planner import PlanningError
+    from repro.launch.cache import use_compile_cache
+    from repro.launch.train import preset_config
     from repro.models.model import make_model
-    from repro.parallel.afd import AFDRuntime, split_nodes
+    from repro.parallel.afd import AFDRuntime, role_devices
     from repro.serving.afd_engine import AFDServeEngine, HFUProbe
     from repro.serving.scheduler import SLOConfig, SLOScheduler
     from repro.serving.workload import generate_trace, get_profile
 
+    use_compile_cache()
     profile = get_profile(args.profile)
-    cfg = configs.get_smoke_config(args.arch)
+    cfg = preset_config(args.arch, args.preset)
     if not cfg.is_moe:
         print(f"error: {args.arch} is dense — the two-role AFD engine "
               "needs routed experts", file=sys.stderr)
         return 2
     model = make_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
-    devs = jax.devices()
-    if len(devs) >= 2:
-        half = len(devs) // 2
-        a_dev, f_dev = split_nodes(devs, half, len(devs) - half)
-    else:
-        a_dev = f_dev = [devs[0]]
+    a_dev, f_dev = role_devices(jax.devices())
     rt = AFDRuntime(cfg, params, a_dev, f_dev)
 
     spec = registry.spec_from_arch_config(cfg)
@@ -415,8 +412,12 @@ def cmd_serve_traffic(args) -> int:
     summary["wall_s"] = wall
 
     doc = {"profile": profile.name, "arch": args.arch, "seed": args.seed,
+           "preset": args.preset,
            "windows": [dataclasses.asdict(w) for w in windows],
-           "summary": summary}
+           "summary": summary,
+           "requests": [{"rid": r.rid, "prompt": r.prompt.tolist(),
+                         "output": list(r.output)}
+                        for r in sorted(eng.completed, key=lambda r: r.rid)]}
     if args.json:
         payload = json.dumps(doc, indent=2, sort_keys=True, default=float)
         if args.json == "-":
@@ -503,19 +504,21 @@ def cmd_serve_fleet(args) -> int:
 
     import jax                                     # lazy: jax-backed command
 
-    from repro import configs
     from repro.api import registry
     from repro.core import planner as pln
     from repro.core.planner import PlanningError
     from repro.fleet.controller import FleetController, FleetReplica
     from repro.fleet.rescaler import ElasticRescaler
+    from repro.launch.cache import use_compile_cache
+    from repro.launch.train import preset_config
     from repro.models.model import make_model
-    from repro.parallel.afd import AFDRuntime, split_nodes
+    from repro.parallel.afd import AFDRuntime, role_devices
     from repro.serving.afd_engine import AFDServeEngine, HFUProbe
     from repro.serving.workload import generate_trace, get_profile
 
+    use_compile_cache()
     profile = get_profile(args.profile)
-    cfg = configs.get_smoke_config(args.arch)
+    cfg = preset_config(args.arch, args.preset)
     if not cfg.is_moe:
         print(f"error: {args.arch} is dense — the two-role AFD engine "
               "needs routed experts", file=sys.stderr)
@@ -531,12 +534,7 @@ def cmd_serve_fleet(args) -> int:
 
     model = make_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
-    devs = jax.devices()
-    if len(devs) >= 2:
-        half = len(devs) // 2
-        a_dev, f_dev = split_nodes(devs, half, len(devs) - half)
-    else:
-        a_dev = f_dev = [devs[0]]
+    a_dev, f_dev = role_devices(jax.devices())
 
     spec = registry.spec_from_arch_config(cfg)
     hw = registry.resolve_hardware(args.hardware)
@@ -651,6 +649,8 @@ DEFAULT_TUNE_SHAPES = [(8, 8, 256, 512), (8, 32, 256, 512),
 
 def cmd_tune(args) -> int:
     from repro.kernels import autotune
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     shapes = _parse_tune_shapes(args.shape) or DEFAULT_TUNE_SHAPES
     t0 = time.perf_counter()
     results = autotune.tune(shapes, reps=args.reps, path=args.out)
@@ -768,7 +768,10 @@ def build_parser() -> argparse.ArgumentParser:
     st.add_argument("--profile", required=True,
                     help="traffic profile (see: python -m repro list traffic)")
     st.add_argument("--arch", default="granite-moe-1b-a400m",
-                    help="smoke architecture to serve (MoE only)")
+                    help="architecture to serve (MoE only)")
+    st.add_argument("--preset", default="smoke", choices=["smoke", "full"],
+                    help="smoke: the arch's reduced config; full: its "
+                         "published widths")
     st.add_argument("--hardware", default="H800",
                     help="hardware spec for the live Eq. 9/HFU probe")
     st.add_argument("--seed", type=int, default=0)
@@ -802,7 +805,10 @@ def build_parser() -> argparse.ArgumentParser:
     sf.add_argument("--profile", required=True,
                     help="traffic profile (see: python -m repro list traffic)")
     sf.add_argument("--arch", default="granite-moe-1b-a400m",
-                    help="smoke architecture to serve (MoE only)")
+                    help="architecture to serve (MoE only)")
+    sf.add_argument("--preset", default="smoke", choices=["smoke", "full"],
+                    help="smoke: the arch's reduced config; full: its "
+                         "published widths")
     sf.add_argument("--hardware", default="H800",
                     help="hardware spec for the HFU probe + rescaler")
     sf.add_argument("--replicas", type=int, default=3)

@@ -11,6 +11,9 @@ pure-jnp oracle (ref.py) and a jit'd public wrapper (ops.py):
   flash_prefill.py      tiled online-softmax prefill attention with
                         causal / sliding-window / bidirectional masks.
 
-All kernels are validated with interpret=True on CPU (this container) and
-target pl.pallas_call + BlockSpec VMEM tiling on real TPU.
+Off the TPU the kernels run in the Pallas interpreter, on the TPU they
+compile with Mosaic (backend.interpret_mode). They are validated against
+their oracles in interpret mode on CPU, compiled for a described TPU v5e
+at published widths (tests/test_tpu_compile.py), and checked against the
+oracles on the chip by chip_smoke.py.
 """

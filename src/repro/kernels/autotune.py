@@ -131,9 +131,8 @@ def tune(shapes: Sequence[Tuple[int, int, int, int]],
     shape (key, winner, per-candidate timings) and rewrites the table at
     ``path`` (module-adjacent default) with the winners merged in.
     """
-    import jax
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    from repro.kernels.backend import interpret_mode
+    interpret = interpret_mode(interpret)
     p = path or _TABLE_PATH
     table = {"version": TABLE_VERSION,
              "entries": dict(load_table(p)["entries"])}

@@ -26,20 +26,24 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import interpret_mode
+
 _MASK = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, out_ref,
+def _kernel(q_off_ref, t_valid_ref,                  # scalar prefetch (B,)
+            q_ref, k_ref, v_ref, out_ref,
             m_ref, l_ref, acc_ref,
-            *, tile_q: int, tile_k: int, t_valid: int, scale: float,
-            causal: bool, window: Optional[int], q_offset: int, out_dtype):
+            *, tile_q: int, tile_k: int, scale: float,
+            causal: bool, window: Optional[int], out_dtype):
+    b = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     n_k = pl.num_programs(3)
@@ -57,12 +61,12 @@ def _kernel(q_ref, k_ref, v_ref, out_ref,
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     # q_offset shifts the query rows to their absolute positions — the
     # chunked-prefill case where q starts mid-sequence against a cache
-    # already holding the prior context.
-    rows = q_offset + qi * tile_q + jax.lax.broadcasted_iota(
+    # already holding the prior context. Both bounds are per sequence.
+    rows = q_off_ref[b] + qi * tile_q + jax.lax.broadcasted_iota(
         jnp.int32, (tile_q, tile_k), 0)
     cols = ki * tile_k + jax.lax.broadcasted_iota(jnp.int32,
                                                   (tile_q, tile_k), 1)
-    mask = cols < t_valid
+    mask = cols < t_valid_ref[b]
     if causal:
         mask = jnp.logical_and(mask, cols <= rows)
     if window is not None:
@@ -87,15 +91,18 @@ def _kernel(q_ref, k_ref, v_ref, out_ref,
 def flash_prefill_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True,
                          window: Optional[int] = None,
-                         q_offset: int = 0,
-                         t_valid: Optional[int] = None,
+                         q_offset: Union[int, jax.Array] = 0,
+                         t_valid: Union[None, int, jax.Array] = None,
                          tile_q: int = 128, tile_k: int = 256,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, S, Hq, d); k, v: (B, T, Hkv, d) → (B, S, Hq, d).
 
-    ``q_offset`` places query row j at absolute position ``q_offset + j``
-    (chunked prefill against a live cache); ``t_valid`` bounds how many
-    leading KV slots hold real keys (default: all T).
+    ``q_offset`` (scalar or (B,)) places query row j of sequence b at
+    absolute position ``q_offset[b] + j`` (chunked prefill against a live
+    cache); ``t_valid`` (scalar or (B,)) bounds how many leading KV slots
+    hold real keys (default: all T). Both ride in as scalar prefetch, so
+    chunk starts neither recompile the kernel nor need to agree across
+    the batch.
     """
     b, s, hq, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
@@ -115,32 +122,39 @@ def flash_prefill_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if t_pad != t:
         kh = jnp.pad(kh, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
+    q_off = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,))
+    t_val = jnp.minimum(
+        jnp.broadcast_to(jnp.asarray(t if t_valid is None else t_valid,
+                                     jnp.int32), (b,)), t)
 
     kernel = functools.partial(
-        _kernel, tile_q=tile_q, tile_k=tile_k,
-        t_valid=(t if t_valid is None else min(t_valid, t)),
-        scale=1.0 / math.sqrt(d), causal=causal, window=window,
-        q_offset=q_offset, out_dtype=q.dtype)
+        _kernel, tile_q=tile_q, tile_k=tile_k, scale=1.0 / math.sqrt(d),
+        causal=causal, window=window, out_dtype=q.dtype)
 
     out = pl.pallas_call(
         kernel,
-        grid=(b, hq, s_pad // tile_q, t_pad // tile_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, tile_q, d),
-                         lambda bi, h, qi, ki: (bi, h, qi, 0)),
-            pl.BlockSpec((1, 1, tile_k, d),
-                         lambda bi, h, qi, ki: (bi, h // group, ki, 0)),
-            pl.BlockSpec((1, 1, tile_k, d),
-                         lambda bi, h, qi, ki: (bi, h // group, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, tile_q, d),
-                               lambda bi, h, qi, ki: (bi, h, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tile_q, 1), jnp.float32),
-            pltpu.VMEM((tile_q, 1), jnp.float32),
-            pltpu.VMEM((tile_q, d), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, hq, s_pad // tile_q, t_pad // tile_k),
+            in_specs=[
+                pl.BlockSpec((1, 1, tile_q, d),
+                             lambda bi, h, qi, ki, *_: (bi, h, qi, 0)),
+                pl.BlockSpec((1, 1, tile_k, d),
+                             lambda bi, h, qi, ki, *_: (bi, h // group, ki,
+                                                        0)),
+                pl.BlockSpec((1, 1, tile_k, d),
+                             lambda bi, h, qi, ki, *_: (bi, h // group, ki,
+                                                        0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, tile_q, d),
+                                   lambda bi, h, qi, ki, *_: (bi, h, qi, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((tile_q, 1), jnp.float32),
+                pltpu.VMEM((tile_q, 1), jnp.float32),
+                pltpu.VMEM((tile_q, d), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b, hq, s_pad, d), q.dtype),
-        interpret=interpret,
-    )(qh, kh, vh)
+        interpret=interpret_mode(interpret),
+    )(q_off, t_val, qh, kh, vh)
     return jnp.moveaxis(out[:, :, :s], 1, 2)

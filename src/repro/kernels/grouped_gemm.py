@@ -28,11 +28,11 @@ to the visit grid):
   * ``row_index`` (M,) fuses the dispatch *gather*: GEMM row r reads
     ``lhs[row_index[r]]``, so the router's sorted token order never has to
     be materialized in HBM. The permutation rides the scalar-prefetch
-    channel; the kernel row-gathers from the resident k-slab of the token
-    buffer (interpret-friendly lowering of the per-row DMA — on real TPU
-    the same scalars steer `make_async_copy` row descriptors).
+    channel (SMEM); each index is read as a scalar and steers one
+    ``make_async_copy`` row DMA from the token buffer in HBM into the
+    tile's VMEM staging buffer.
   * ``out_index`` (M,) fuses the combine-side *unpermute scatter*: the
-    accumulator epilogue scatters GEMM row r to ``out[out_index[r]]``
+    accumulator epilogue DMAs GEMM row r to ``out[out_index[r]]`` in HBM
     instead of writing tile-contiguous rows, returning outputs already in
     token order. Destinations must be unique per valid row (a permutation,
     which router unpermute always is).
@@ -50,13 +50,14 @@ paper's dead-zone boundary move; see core/budget.weight_bytes_per_param):
 VMEM budget per grid step: lhs tile (tile_m × tile_k) + rhs block
 (tile_k × tile_n) + f32 accumulator (tile_m × tile_n) — with the default
 128×128×512 tiling ≈ 0.5 MB, comfortably inside the ~16 MB v5e VMEM so the
-pipeline can double-buffer. The fused gather/scatter variants instead keep
-the full token slab (rows × tile_k) / output slab (rows × tile_n) resident,
-which is the right trade at decode token counts (≤ a few thousand rows).
+pipeline can double-buffer. The fused variants add one f32 row-staging
+buffer (tile_m × tile_k, or tile_m × tile_n for the scatter) whatever the
+token count; the dequant scales ride in SMEM as scalar prefetch.
 
 Validated in interpret mode on CPU against ``ref.grouped_gemm_ref`` over
 shape/dtype sweeps (tests/test_kernels_grouped_gemm.py,
-tests/test_kernels_quant.py).
+tests/test_kernels_quant.py); tests/test_tpu_compile.py compiles every
+form for a TPU v5e at published widths.
 """
 
 from __future__ import annotations
@@ -67,6 +68,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import interpret_mode
 
 MXU_SUBLANE = 8                 # f32 sublane multiple of the MXU tile
 
@@ -157,7 +160,7 @@ def grouped_gemm_pallas(lhs: jax.Array, rhs: jax.Array,
                         row_index: Optional[jax.Array] = None,
                         out_index: Optional[jax.Array] = None,
                         out_rows: Optional[int] = None,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """Grouped GEMM via the visit-steered Pallas kernel.
 
     Weight quantization (inferred from ``scales``):
@@ -174,8 +177,8 @@ def grouped_gemm_pallas(lhs: jax.Array, rhs: jax.Array,
         (a permutation over valid rows; ``out_rows`` sets the output row
         count, default M). Un-targeted rows are zero.
 
-    ``interpret=True`` (the default in this CPU container) runs the kernel
-    body in the Pallas interpreter; on real TPU pass ``interpret=False``.
+    ``interpret=None`` runs the kernel body in the Pallas interpreter off
+    the TPU and compiles it with Mosaic on the TPU (``interpret_mode``).
     """
     int4 = scales is not None and scales.ndim == 2
     g = rhs.shape[0]
@@ -208,14 +211,14 @@ def grouped_gemm_pallas(lhs: jax.Array, rhs: jax.Array,
     m_pad = _cdiv(m, tile_m) * tile_m
     n_pad = _cdiv(n, tile_n) * tile_n
     k_pad = _cdiv(k, tile_k) * tile_k
-    if row_index is None:
-        lhs_p = jnp.pad(lhs, ((0, m_pad - m), (0, k_pad - k)))
-    else:
-        # Fused gather: the kernel keeps the whole token slab's k-slice
-        # resident and row-gathers it by the prefetched permutation.
-        src_rows = lhs.shape[0]
-        src_pad = _cdiv(src_rows, MXU_SUBLANE) * MXU_SUBLANE
-        lhs_p = jnp.pad(lhs, ((0, src_pad - src_rows), (0, k_pad - k)))
+    gather = row_index is not None
+    scatter = out_index is not None
+    # The fused gather DMAs single token rows out of HBM, so the token
+    # buffer keeps its own row count (as 32-bit rows); the plain path tiles
+    # rows in VMEM.
+    lhs_p = jnp.pad(lhs, ((0, 0 if gather else m_pad - m), (0, k_pad - k)))
+    if gather:
+        lhs_p = _dma_rows(lhs_p)
     if int4:
         rhs_p = jnp.pad(rhs, ((0, 0), (0, k_pad // 2 - rhs.shape[1]),
                               (0, n_pad - n)))
@@ -225,44 +228,41 @@ def grouped_gemm_pallas(lhs: jax.Array, rhs: jax.Array,
     visit_m, visit_g, offsets = build_visits(group_sizes, m, tile_m, g)
     n_visits = int(visit_m.shape[0])
     n_k_tiles = k_pad // tile_k
-    grid = (n_pad // tile_n, n_visits, n_k_tiles)
-
-    scatter = out_index is not None
+    n_n_tiles = n_pad // tile_n
+    grid = (n_n_tiles, n_visits, n_k_tiles)
     o_rows = m if out_rows is None else int(out_rows)
-    o_pad = (_cdiv(o_rows, MXU_SUBLANE) * MXU_SUBLANE if scatter else m_pad)
 
-    # Scalar-prefetch operands: visit steering + optional permutations.
+    # Scalar-prefetch operands (SMEM): visit steering, the permutations and
+    # the dequant scales — every per-row or per-expert value the kernel
+    # reads is a scalar load.
     prefetch = [visit_m, visit_g, offsets]
-    if row_index is not None:
+    if gather:
         idx_p = jnp.pad(row_index.astype(jnp.int32), (0, m_pad - m))
-        prefetch.append(jnp.minimum(idx_p, lhs_p.shape[0] - 1))
+        prefetch.append(jnp.clip(idx_p, 0, lhs.shape[0] - 1))
     if scatter:
-        oidx_p = jnp.pad(out_index.astype(jnp.int32), (0, m_pad - m))
-        prefetch.append(jnp.minimum(oidx_p, o_pad - 1))
+        prefetch.append(jnp.pad(out_index.astype(jnp.int32), (0, m_pad - m)))
+    if scales is not None:
+        prefetch.append(scales.astype(jnp.float32).reshape(-1))
     n_pref = len(prefetch)
-    row_pos = 3 if row_index is not None else None
-    oidx_pos = (3 + (row_index is not None)) if scatter else None
 
     def kernel(*refs):
-        pref = refs[:n_pref]
-        vm_ref, vg_ref, off_ref = pref[0], pref[1], pref[2]
-        ins = refs[n_pref:-2]
-        lhs_ref, rhs_ref = ins[0], ins[1]
-        scale_ref = ins[2] if scales is not None else None
-        out_ref, acc_ref = refs[-2], refs[-1]
+        pref = list(refs[:n_pref])
+        vm_ref, vg_ref, off_ref = pref[:3]
+        row_ref = pref.pop(3) if gather else None
+        dest_ref = pref.pop(3) if scatter else None
+        scale_ref = pref[3] if scales is not None else None
+        lhs_ref, rhs_ref = refs[n_pref], refs[n_pref + 1]
+        out_ref, acc_ref = refs[n_pref + 2 + scatter], refs[n_pref + 3
+                                                              + scatter]
+        bufs = list(refs[n_pref + 4 + scatter:])
 
+        j = pl.program_id(0)
         v = pl.program_id(1)
         kt = pl.program_id(2)
         n_vis = pl.num_programs(1)
         gid = vg_ref[v]
         mt = vm_ref[v]
-
-        if scatter:
-            # The output block is the full row slab for this n-tile; zero it
-            # once at the first grid step of each j before any flush lands.
-            @pl.when(jnp.logical_and(v == 0, kt == 0))
-            def _zero():
-                out_ref[...] = jnp.zeros_like(out_ref)
+        base = mt * tile_m
 
         # First (visit, k-tile) touching this output tile initialises the
         # accumulator. Visits sharing an m-tile are consecutive in v.
@@ -274,35 +274,46 @@ def grouped_gemm_pallas(lhs: jax.Array, rhs: jax.Array,
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         # Row mask: rows of this tile belonging to group gid.
-        rows = mt * tile_m + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_m, 1), 0)
+        rows = base + jax.lax.broadcasted_iota(jnp.int32, (tile_m, 1), 0)
         valid = jnp.logical_and(gid < g, rows < m)
         lo = off_ref[jnp.minimum(gid, g - 1)]
         hi = off_ref[jnp.minimum(gid + 1, g)]
         mask = jnp.logical_and(valid,
                                jnp.logical_and(rows >= lo, rows < hi))
 
-        if row_pos is not None:
-            src = pref[row_pos][pl.ds(mt * tile_m, tile_m)]
-            x = jnp.take(lhs_ref[...], src, axis=0)
+        if gather:
+            # Fused dispatch gather: one row DMA per GEMM row, steered by
+            # the prefetched permutation, from the HBM token buffer into
+            # the tile's VMEM staging buffer.
+            xbuf, sem = bufs.pop(0), bufs.pop(0)
+
+            def row_copy(r):
+                return pltpu.make_async_copy(
+                    lhs_ref.at[row_ref[base + r], :,
+                               pl.ds(kt * tile_k, tile_k)],
+                    xbuf.at[r], sem)
+
+            _for_rows(tile_m, lambda r: row_copy(r).start())
+            _for_rows(tile_m, lambda r: row_copy(r).wait())
+            x = xbuf[...].reshape(tile_m, tile_k).astype(lhs.dtype)
         else:
             x = lhs_ref[...]
         x = jnp.where(mask, x, jnp.zeros_like(x))
 
         w = rhs_ref[0]
         if scale_ref is None:
-            acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+            acc_ref[...] += _mxu_dot(x, w)
         else:
+            e = jnp.minimum(gid, g - 1)
             if int4:
                 w = (_unpack_int4(w, tile_k, tile_n).astype(jnp.float32)
-                     * scale_ref[0, 0])
+                     * scale_ref[e * n_n_tiles + j])
             else:
                 # int8 weight-only quantization: dequantise the VMEM tile
                 # with the per-expert scale. HBM→VMEM weight traffic halves
                 # vs bf16 — the §Perf H1 "memory-floor" lever.
-                w = w.astype(jnp.float32) * scale_ref[0]
-            acc_ref[...] += jnp.dot(x.astype(jnp.float32), w,
-                                    preferred_element_type=jnp.float32)
+                w = w.astype(jnp.float32) * scale_ref[e]
+            acc_ref[...] += _mxu_dot(x.astype(jnp.float32), w)
 
         # Flush on the last (visit, k-tile) for this m-tile.
         is_last = jnp.logical_or(
@@ -311,66 +322,105 @@ def grouped_gemm_pallas(lhs: jax.Array, rhs: jax.Array,
         @pl.when(jnp.logical_and(is_last, kt == n_k_tiles - 1))
         def _flush():
             if scatter:
-                # Unpermute epilogue: scatter the finished tile's rows to
-                # their token-order destinations. Valid destinations are
-                # unique (a permutation), so the adds never collide; invalid
-                # rows contribute zero to row 0.
-                rvalid = rows[:, 0] < m
-                dest = pref[oidx_pos][pl.ds(mt * tile_m, tile_m)]
-                dest = jnp.where(rvalid, dest, 0)
-                vals = jnp.where(rvalid[:, None], acc_ref[...],
-                                 jnp.zeros_like(acc_ref)).astype(out_dtype)
-                out_ref[...] = out_ref[...].at[dest].add(vals)
+                # Unpermute epilogue: one row DMA per valid GEMM row into
+                # its token-order destination. Destinations are unique (a
+                # permutation), so no two rows write the same output row.
+                obuf, sem = bufs
+                obuf[...] = acc_ref[...].astype(out_dtype).astype(
+                    jnp.float32).reshape(obuf.shape)
+
+                def row_copy(r):
+                    return pltpu.make_async_copy(
+                        obuf.at[r],
+                        out_ref.at[dest_ref[base + r], :,
+                                   pl.ds(j * tile_n, tile_n)], sem)
+
+                def when_valid(fn):
+                    return lambda r: pl.when(base + r < m)(
+                        lambda: fn(row_copy(r)))
+
+                _for_rows(tile_m, when_valid(lambda c: c.start()))
+                _for_rows(tile_m, when_valid(lambda c: c.wait()))
             else:
                 out_ref[...] = acc_ref[...].astype(out_dtype)
-
-    def _lhs_index(j, v, kt, *pref):
-        if row_pos is not None:
-            return (0, kt)               # whole token slab, k-slice kt
-        return (pref[0][v], kt)          # visit's m-tile
 
     def _rhs_index(j, v, kt, *pref):
         # vg == g marks an empty surplus visit; clamp the DMA index into
         # range — the kernel's row mask zeroes its contribution.
         return (jnp.minimum(pref[1][v], g - 1), kt, j)
 
-    def _out_index(j, v, kt, *pref):
-        if scatter:
-            return (0, j)                # whole output slab, n-tile j
-        return (pref[0][v], j)
-
-    lhs_block = ((lhs_p.shape[0], tile_k) if row_pos is not None
-                 else (tile_m, tile_k))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     rhs_block = (1, tile_k // 2, tile_n) if int4 else (1, tile_k, tile_n)
-    in_specs = [pl.BlockSpec(lhs_block, _lhs_index),
+    in_specs = [hbm if gather else pl.BlockSpec(
+                    (tile_m, tile_k), lambda j, v, kt, *pref: (pref[0][v], kt)),
                 pl.BlockSpec(rhs_block, _rhs_index)]
     operands = prefetch + [lhs_p, rhs_p]
-    if scales is not None:
-        if int4:
-            in_specs.append(pl.BlockSpec(
-                (1, 1), lambda j, v, kt, *pref:
-                (jnp.minimum(pref[1][v], g - 1), j)))
-        else:
-            in_specs.append(pl.BlockSpec(
-                (1,), lambda j, v, kt, *pref:
-                (jnp.minimum(pref[1][v], g - 1),)))
-        operands.append(scales.astype(jnp.float32))
+    scratch = [pltpu.VMEM((tile_m, tile_n), jnp.float32)]
+    if gather:
+        scratch += [pltpu.VMEM((tile_m, 1, tile_k), jnp.float32),
+                    pltpu.SemaphoreType.DMA(())]
+    aliases = {}
+    if scatter:
+        # The output lives in HBM and only valid rows are written, so it
+        # starts as a zero buffer aliased to the result.
+        zeros = jnp.zeros((o_rows, 1, n_pad), jnp.float32)
+        in_specs.append(hbm)
+        operands.append(zeros)
+        aliases = {len(operands) - 1: 0}
+        out_spec = hbm
+        out_shape = jax.ShapeDtypeStruct(zeros.shape, zeros.dtype)
+        scratch += [pltpu.VMEM((tile_m, 1, tile_n), jnp.float32),
+                    pltpu.SemaphoreType.DMA(())]
+    else:
+        out_spec = pl.BlockSpec((tile_m, tile_n),
+                                lambda j, v, kt, *pref: (pref[0][v], j))
+        out_shape = jax.ShapeDtypeStruct((m_pad, n_pad), out_dtype)
 
-    out_block = (o_pad, tile_n) if scatter else (tile_m, tile_n)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_pref,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(out_block, _out_index),
-            scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.float32)],
+            out_specs=out_spec,
+            scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((o_pad if scatter else m_pad, n_pad),
-                                       out_dtype),
-        interpret=interpret,
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        interpret=interpret_mode(interpret),
     )(*operands)
-    return out[:o_rows if scatter else m, :n]
+    if scatter:
+        return out.reshape(o_rows, n_pad)[:, :n].astype(out_dtype)
+    return out[:m, :n]
+
+
+# Row DMAs. Mosaic moves a single row only as a whole leading-axis slice
+# of a 32-bit (R, 1, W) array: a one-row slice of a tiled 2-D array, or of
+# a packed 16-bit one, is refused. So the fused permute carries rows as
+# (R, 1, W) float32 — exact for bf16/f16 values, which round-trip through
+# f32 unchanged — and casts back on the far side of the DMA.
+
+def _dma_rows(x: jax.Array) -> jax.Array:
+    """(R, C) → (R, 1, C) float32 rows for single-row DMAs."""
+    return x.astype(jnp.float32).reshape(x.shape[0], 1, x.shape[1])
+
+
+def _mxu_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """f32-accumulating dot at the operands' own precision: full for f32,
+    one pass for bf16. Explicit, so that an ambient
+    ``jax_default_matmul_precision`` cannot ask Mosaic for an fp32
+    contraction of bf16 operands, which it refuses."""
+    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    return jnp.dot(a, b, precision=prec, preferred_element_type=jnp.float32)
+
+
+def _for_rows(n: int, fn) -> None:
+    """``fn(r)`` for r in [0, n) as a kernel loop (per-row DMA issue)."""
+    def body(r, carry):
+        fn(r)
+        return carry
+    jax.lax.fori_loop(0, n, body, 0)
 
 
 # ---------------------------------------------------------------------------

@@ -2,14 +2,15 @@
 
 Every op has three interchangeable implementations:
 
-  * ``pallas`` — the TPU kernel (interpret-mode on this CPU container).
+  * ``pallas`` — the TPU kernel (interpret mode off the TPU).
   * ``xla``    — the best XLA-native lowering (``lax.ragged_dot`` for the
     grouped GEMM, masked einsum for decode attention). This is what the
     full-scale dry-run lowers, so cost_analysis prices a real path.
   * ``ref``    — the pure-jnp oracle (kernels/ref.py).
 
-``default_impl()`` picks ``xla`` on CPU (interpret-mode Pallas is an
-emulator, far too slow at production shapes) and ``pallas`` on TPU.
+``default_impl()`` picks ``xla`` off the TPU (interpret-mode Pallas is an
+emulator, far too slow at production shapes) and ``pallas`` on the TPU;
+``kernels/backend.py`` is the one place that asks which platform this is.
 """
 
 from __future__ import annotations
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.kernels import autotune as _autotune
 from repro.kernels import ref as _ref
+from repro.kernels.backend import on_tpu
 from repro.kernels.grouped_gemm import (dequantize_experts,
                                         dequantize_experts_int4,
                                         grouped_gemm_pallas)
@@ -30,11 +32,7 @@ _IMPLS = ("pallas", "xla", "ref")
 
 
 def default_impl() -> str:
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "xla"
+    return "pallas" if on_tpu() else "xla"
 
 
 # ---------------------------------------------------------------------------
@@ -78,12 +76,10 @@ def grouped_gemm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
             # Each weight tile must dequantise with one scalar: force the
             # n-tiling to the quantization block grid.
             tile_n = rhs.shape[2] // scales.shape[1]
-        interpret = jax.devices()[0].platform != "tpu"
         return grouped_gemm_pallas(lhs, rhs, group_sizes, tile_m=tile_m,
                                    tile_n=tile_n, tile_k=tile_k,
                                    scales=scales, row_index=row_index,
-                                   out_index=out_index, out_rows=out_rows,
-                                   interpret=interpret)
+                                   out_index=out_index, out_rows=out_rows)
     if impl in ("xla", "ref"):
         if scales is not None:
             rhs = (dequantize_experts_int4(rhs, scales) if int4
@@ -115,10 +111,8 @@ def splitkv_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     impl = impl or default_impl()
     if impl == "pallas":
-        interpret = jax.devices()[0].platform != "tpu"
         return splitkv_attention_pallas(q, k, v, lengths, chunk=chunk,
-                                        return_lse=return_lse,
-                                        interpret=interpret)
+                                        return_lse=return_lse)
     if impl in ("xla", "ref"):
         out = _ref.splitkv_attention_ref(q, k, v, lengths)
         if return_lse:
@@ -136,45 +130,27 @@ def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             causal: bool = True,
                             window: Optional[int] = None,
                             impl: Optional[str] = None,
-                            q_offset: int = 0,
-                            t_valid: Optional[int] = None,
+                            q_offset=0,
+                            t_valid=None,
                             tile_q: int = 128,
                             tile_k: int = 256) -> jax.Array:
     """Tiled online-softmax prefill attention (B, S, Hq, d).
 
-    ``q_offset``/``t_valid`` support chunked prefill against a live cache:
-    query row j sits at absolute position ``q_offset + j`` and only the
-    first ``t_valid`` KV slots hold real keys.
+    ``q_offset``/``t_valid`` (scalars or (B,) arrays) support chunked
+    prefill against a live cache: query row j of sequence b sits at
+    absolute position ``q_offset[b] + j`` and only the first
+    ``t_valid[b]`` KV slots hold real keys.
     """
     from repro.kernels.flash_prefill import flash_prefill_pallas
     impl = impl or default_impl()
     if impl == "pallas":
-        interpret = jax.devices()[0].platform != "tpu"
         return flash_prefill_pallas(q, k, v, causal=causal, window=window,
                                     q_offset=q_offset, t_valid=t_valid,
-                                    tile_q=tile_q, tile_k=tile_k,
-                                    interpret=interpret)
+                                    tile_q=tile_q, tile_k=tile_k)
     # XLA / ref: dense masked attention (the models/attention.py chunked
     # scan is the production XLA path; this is the oracle form)
-    b, s, hq, d = q.shape
-    t, hkv = k.shape[1], k.shape[2]
-    group = hq // hkv
-    qg = q.reshape(b, s, hkv, group, d).astype(jnp.float32)
-    scores = jnp.einsum("bskgd,btkd->bkgst", qg, k.astype(jnp.float32))
-    scores = scores / jnp.sqrt(jnp.asarray(d, jnp.float32))
-    rows = q_offset + jnp.arange(s)[:, None]
-    cols = jnp.arange(t)[None, :]
-    mask = jnp.ones((s, t), bool)
-    if t_valid is not None:
-        mask = mask & (cols < t_valid)
-    if causal:
-        mask = mask & (cols <= rows)
-    if window is not None:
-        mask = mask & (rows - cols < window)
-    scores = jnp.where(mask[None, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, s, hq, d).astype(q.dtype)
+    return _ref.flash_prefill_ref(q, k, v, causal=causal, window=window,
+                                  q_offset=q_offset, t_valid=t_valid)
 
 
 def _attention_lse(q: jax.Array, k: jax.Array,

@@ -88,6 +88,38 @@ def splitkv_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(b, hq, d).astype(q.dtype)
 
 
+def flash_prefill_ref(q: jax.Array, k: jax.Array, v: jax.Array,
+                      causal: bool = True, window: Optional[int] = None,
+                      q_offset=0, t_valid=None) -> jax.Array:
+    """Reference prefill attention: dense masked float32 softmax.
+
+    q: (B, S, Hq, d); k, v: (B, T, Hkv, d). Query row j of sequence b sits
+    at absolute position ``q_offset[b] + j`` and sees KV slots
+    ``< t_valid[b]`` (both scalars or (B,) arrays).
+    """
+    b, s, hq, d = q.shape
+    t, hkv = k.shape[1], k.shape[2]
+    group = hq // hkv
+    qg = q.reshape(b, s, hkv, group, d).astype(jnp.float32)
+    scores = jnp.einsum("bskgd,btkd->bkgst", qg, k.astype(jnp.float32))
+    scores = scores / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    off = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,))
+    rows = off[:, None, None] + jnp.arange(s)[None, :, None]   # (B, S, 1)
+    cols = jnp.arange(t)[None, None, :]
+    mask = jnp.ones((b, s, t), bool)
+    if t_valid is not None:
+        tv = jnp.broadcast_to(jnp.asarray(t_valid, jnp.int32), (b,))
+        mask = mask & (cols < tv[:, None, None])
+    if causal:
+        mask = mask & (cols <= rows)
+    if window is not None:
+        mask = mask & (rows - cols < window)
+    scores = jnp.where(mask[:, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
+    return out.reshape(b, s, hq, d).astype(q.dtype)
+
+
 def moe_ffn_ref(x: jax.Array, router_w: jax.Array, w_in: jax.Array,
                 w_out: jax.Array, top_k: int,
                 renorm: bool = True,

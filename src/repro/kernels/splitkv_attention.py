@@ -27,11 +27,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import interpret_mode
 
 _MASK = -1e30
 
@@ -73,14 +76,14 @@ def _kernel(lengths,                         # scalar prefetch (B,)
     def _flush():
         out_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(out_dtype)
         if return_lse:
-            lse_ref[0, 0] = (m_ref[...] + jnp.log(l_ref[...]))[:, 0]
+            lse_ref[0, 0] = m_ref[...] + jnp.log(l_ref[...])
 
 
 def splitkv_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                              lengths: jax.Array, *,
                              chunk: int = 256,
                              return_lse: bool = False,
-                             interpret: bool = True):
+                             interpret: Optional[bool] = None):
     """q: (B, Hq, d); k, v: (B, T, Hkv, d); lengths: (B,) int32.
 
     Returns (B, Hq, d), plus per-head log-sum-exp (B, Hq) when
@@ -105,9 +108,12 @@ def splitkv_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     out_specs = [pl.BlockSpec((1, 1, group, d),
                               lambda bi, h, ti, ln: (bi, h, 0, 0))]
     if return_lse:
-        out_shapes.append(jax.ShapeDtypeStruct((b, hkv, group), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, group),
-                                      lambda bi, h, ti, ln: (bi, h, 0)))
+        # (group, 1) trailing dims span the whole array, as Mosaic's block
+        # rule asks of blocks smaller than an (8, 128) tile.
+        out_shapes.append(jax.ShapeDtypeStruct((b, hkv, group, 1),
+                                               jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, group, 1),
+                                      lambda bi, h, ti, ln: (bi, h, 0, 0)))
 
     kernel = functools.partial(
         _kernel, chunk=chunk, scale=1.0 / math.sqrt(d), out_dtype=q.dtype,
@@ -141,7 +147,7 @@ def splitkv_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             ],
         ),
         out_shape=out_shapes if return_lse else out_shapes[0],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lengths.astype(jnp.int32), qg, kh, vh)
 
     if return_lse:

@@ -13,9 +13,8 @@ from __future__ import annotations
 
 from typing import Sequence
 
+import jax
 import numpy as np
-
-from repro import compat
 
 CHIPS_PER_NODE = 8
 
@@ -23,12 +22,13 @@ CHIPS_PER_NODE = 8
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """Arbitrary mesh with Auto axis types (tests / AFD role meshes)."""
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def nodes_in_mesh(mesh) -> int:

@@ -4,7 +4,7 @@ scheduler, optional AFD two-role execution, and a fault-injection drill.
     PYTHONPATH=src python -m repro.launch.serve \
         --arch kimi-k2-1t-a32b --preset smoke --requests 16 --slots 4 \
         --mode ep
-    ... --mode afd --n-a-nodes 4 --n-f-nodes 4   # two-role AFD runtime
+    ... --mode afd      # two-role AFD runtime (roles colocate on one device)
     ... --fail-at 5                              # kill a node mid-run
 """
 
@@ -17,9 +17,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import use_compile_cache
 from repro.launch.train import preset_config
 from repro.models.model import make_model
-from repro.parallel.afd import AFDRuntime, split_nodes
+from repro.parallel.afd import AFDRuntime, role_devices
 from repro.serving.engine import DecodeEngine, Request
 from repro.serving.scheduler import SLOConfig, SLOScheduler
 
@@ -35,13 +36,12 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--prompt-len", type=int, default=8)
-    ap.add_argument("--n-a-nodes", type=int, default=4)
-    ap.add_argument("--n-f-nodes", type=int, default=4)
     ap.add_argument("--fail-at", type=int, default=None,
                     help="tick at which to simulate a node failure")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = preset_config(args.arch, args.preset)
     model = make_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
@@ -53,9 +53,7 @@ def main() -> None:
         if not cfg.is_moe:
             raise SystemExit(f"{cfg.name} is dense — AFD inapplicable "
                              "(DESIGN.md §Arch-applicability); use --mode ep")
-        devs = jax.devices()
-        a_dev, f_dev = split_nodes(devs, min(args.n_a_nodes, len(devs) // 2),
-                                   min(args.n_f_nodes, len(devs) // 2))
+        a_dev, f_dev = role_devices(jax.devices())
         rt = AFDRuntime(cfg, params, a_dev, f_dev)
         caches, pos = rt.init_cache(args.slots, args.max_len)
         toks = jnp.asarray(rng.randint(1, cfg.vocab_size,

@@ -25,6 +25,7 @@ import time
 import jax
 
 from repro import configs
+from repro.launch.cache import use_compile_cache
 from repro.models.model import make_model
 from repro.training import checkpoint as ckpt_mod
 from repro.training import data as data_mod
@@ -77,6 +78,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = preset_config(args.arch, args.preset)
     model = make_model(cfg)
     print(f"arch={cfg.name} preset={args.preset} "

@@ -204,10 +204,13 @@ def attention_prefill_cached(params, cfg: ArchConfig, x: jax.Array,
     pos + j. Future chunk rows mask to exactly-zero probabilities, which
     annihilate their (already written) values.
 
-    ``impl="pallas"`` routes the chunk through the flash-prefill kernel
-    (``q_offset`` places the chunk mid-sequence) — the TPU path; online
-    softmax is not bit-exact vs the dense reference, so the default (None →
-    dense masked) is what the serving engine's bit-exactness tests pin.
+    ``impl="pallas"`` routes the chunk through the flash-prefill kernel,
+    whose per-sequence ``q_offset`` places each row's chunk mid-sequence —
+    the TPU path; online softmax is not bit-exact vs the dense reference,
+    so the default (None → dense masked) is what the serving engine's
+    bit-exactness tests pin. A sliding-window cache is a ring whose slots
+    are not in position order, which the kernel cannot address: such
+    configs take the dense masked path on every platform.
     """
     b, c, _ = x.shape
     q = _project_q(params, cfg, x)
@@ -218,17 +221,11 @@ def attention_prefill_cached(params, cfg: ArchConfig, x: jax.Array,
         k_new = apply_rope(k_new, positions, cfg.rope_theta)
     new_cache = kvcache.write_kv_chunk(cfg, cache, k_new, v_new, pos)
     t = new_cache["k"].shape[1]
-    if (impl == "pallas" and cfg.sliding_window is None
-            and bool(jnp.all(pos == pos[0]))):
-        # kernel q_offset is scalar — needs a uniform chunk start (the
-        # engine prefills one sequence at a time, so this always holds
-        # there); ragged batches fall back to the dense masked path.
+    if impl == "pallas" and cfg.sliding_window is None:
         from repro.kernels import ops as kops
-        off = int(pos[0])
         out = kops.flash_prefill_attention(
             q, new_cache["k"], new_cache["v"], causal=cfg.causal,
-            window=cfg.sliding_window, impl="pallas",
-            q_offset=off, t_valid=min(off + c, t))
+            impl="pallas", q_offset=pos, t_valid=jnp.minimum(pos + c, t))
         out = out.reshape(b, c, -1)
         out = shard(out, "batch", "seq", "heads")
     else:

@@ -183,9 +183,13 @@ def set_ep_forward(fn) -> None:
 def moe_forward(params, cfg: ArchConfig, x: jax.Array,
                 mode: str = "train") -> Tuple[jax.Array, jax.Array]:
     """Dispatch by phase: capacity path for train (differentiable),
-    sorted/grouped path for decode. Returns (out, aux_loss)."""
+    dropless sorted/grouped path for serving (prefill and decode), so a
+    prompt's logits do not depend on how many of its tokens share an
+    expert. Under an installed EP strategy, prefill takes the capacity
+    all-to-all lowering of train. Returns (out, aux_loss)."""
     if _EP_FORWARD is not None:
-        return _EP_FORWARD(params, cfg, x, mode)
+        return _EP_FORWARD(params, cfg, x,
+                           "train" if mode == "prefill" else mode)
     if mode == "train":
         return moe_capacity(params, cfg, x)
     out = moe_sorted(params, cfg, x)

@@ -91,8 +91,7 @@ def layer_forward(params, cfg: ArchConfig, spec: LayerSpec, x: jax.Array,
     if has_ffn(cfg, spec):
         h = apply_norm(params["ln2"], cfg, x)
         if spec.moe:
-            ffn_mode = "train" if mode in ("train", "prefill") else "decode"
-            out, aux = moe.moe_forward(params["moe"], cfg, h, mode=ffn_mode)
+            out, aux = moe.moe_forward(params["moe"], cfg, h, mode=mode)
         else:
             out = apply_mlp(params["mlp"], cfg, h)
         x = x + out

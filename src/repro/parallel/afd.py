@@ -5,7 +5,8 @@ Role split (node granularity, paper §3.1 assumption):
   * **A-role** — embeddings, every attention/Mamba mixer, norms, dense
     MLPs, shared experts, the router, and the LM head. 1-D TP mesh.
   * **F-role** — the routed-expert weights of every MoE layer, sharded
-    expert-parallel over the F devices.
+    expert-parallel over the F devices: each F device holds E/N_F experts
+    and runs the grouped GEMM for those alone (``make_expert_ffn``).
 
 Per MoE layer and micro-batch the runtime performs the paper's M2N cycle:
 
@@ -30,7 +31,7 @@ split; the planner reports it instead).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -137,49 +138,22 @@ class AFDRuntime:
         a_params, f_layers = split_roles(params, cfg)
         self.a_params = jax.device_put(
             a_params, NamedSharding(self.a_mesh, P()))
-        ef = len(f_devices)
-        espec = (P("expert", None, None) if cfg.n_experts % ef == 0
-                 else P(None, None, None))   # uneven E: replicate on F
+        # Experts shard evenly over F: an uneven E is padded with zero
+        # experts the router never selects.
+        pad = -cfg.n_experts % len(f_devices)
+        espec = NamedSharding(self.f_mesh, P("expert", None, None))
         self.f_layers = [
             None if fl is None else {
-                "wi": jax.device_put(fl["wi"],
-                                     NamedSharding(self.f_mesh, espec)),
-                "wo": jax.device_put(fl["wo"],
-                                     NamedSharding(self.f_mesh, espec)),
-            }
+                w: jax.device_put(
+                    jnp.pad(fl[w], ((0, pad), (0, 0), (0, 0))), espec)
+                for w in ("wi", "wo")}
             for fl in f_layers
         ]
 
-        self._ffn_fn = jax.jit(self._ffn_impl)
+        self._ffn_fn = make_expert_ffn(cfg, self.f_mesh, gemm_impl)
+        self._flash_attn = make_chunk_attention(cfg, self.a_mesh)
         self._tok_sharding_f = NamedSharding(self.f_mesh, P())
         self._tok_sharding_a = NamedSharding(self.a_mesh, P())
-
-    # ---- F-role program ----------------------------------------------------
-
-    def _ffn_impl(self, wi, wo, tokens, topw, topi):
-        """Routed-expert FFN given gating (router ran on the A role).
-
-        Uses the fused router permute (PR 5): the dispatch gather rides
-        into the first grouped GEMM as ``row_index`` (no (N·k, D) sorted
-        copy materialises — at prefill chunk sizes that copy is
-        chunk·top_k·d_model) and the combine unpermute rides out of the
-        second as an ``out_index`` scatter. Bit-exact vs the unfused
-        gather→GEMM→take composition on every impl.
-        """
-        cfg = self.cfg
-        n, d = tokens.shape
-        sort_idx, _, group_sizes = moe_mod.sort_by_expert(
-            topi, cfg.n_experts)
-        h = kops.grouped_gemm(tokens, wi.astype(tokens.dtype), group_sizes,
-                              impl=self.gemm_impl,
-                              row_index=sort_idx // cfg.top_k)
-        gate, up = jnp.split(h, 2, axis=-1)
-        h = jax.nn.silu(gate) * up
-        ys = kops.grouped_gemm(h, wo.astype(tokens.dtype), group_sizes,
-                               impl=self.gemm_impl, out_index=sort_idx,
-                               out_rows=n * cfg.top_k)
-        y = ys.reshape(n, cfg.top_k, d)
-        return jnp.einsum("nkd,nk->nd", y, topw.astype(tokens.dtype))
 
     # ---- per-layer A-role pieces -------------------------------------------
 
@@ -299,8 +273,11 @@ class AFDRuntime:
         cfg = self.cfg
         h = apply_norm(lp["ln1"], cfg, x)
         if spec.kind == "attn":
-            mix, nc = attn_mod.attention_prefill_cached(
-                lp["attn"], cfg, h, cache, pos, impl=attn_impl)
+            if attn_impl == "pallas":
+                mix, nc = self._flash_attn(lp["attn"], h, cache, pos)
+            else:
+                mix, nc = attn_mod.attention_prefill_cached(
+                    lp["attn"], cfg, h, cache, pos)
             return x + mix, nc
         # SSM mixers are an O(1)-per-token recurrence with no cached-state
         # batched form here — step the chunk sequentially (bit-identical to
@@ -365,6 +342,82 @@ class AFDRuntime:
         return logits, caches, pos
 
 
+# ---------------------------------------------------------------------------
+# F-role program
+# ---------------------------------------------------------------------------
+
+def make_expert_ffn(cfg: ArchConfig, f_mesh: Mesh,
+                    gemm_impl: Optional[str] = None):
+    """The F role's routed-expert FFN as one program over ``f_mesh``.
+
+    ``fn(wi, wo, tokens, topw, topi)``: ``wi``/``wo`` are sharded over the
+    mesh's "expert" axis; tokens and gating (the router ran on the A role)
+    are replicated. Expert parallelism is explicit (``jax.shard_map``):
+    each F device maps the global expert ids onto the experts it holds,
+    runs the grouped GEMM over its local groups alone and contributes a
+    partial combine, summed over "expert". No device ever reads another's
+    expert weights — XLA cannot partition the Pallas call, so a sharded
+    operand handed to it unwrapped would be all-gathered instead.
+
+    The dispatch gather rides into the first grouped GEMM as ``row_index``
+    (no (N·k, D) sorted copy materialises) and the combine unpermute rides
+    out of the second as an ``out_index`` scatter. With one F device the
+    arithmetic is that of the single-program ``moe_sorted``, bit for bit.
+    """
+    def local_ffn(wi, wo, tokens, topw, topi):
+        n, d = tokens.shape
+        e_local = wi.shape[0]
+        local = topi - jax.lax.axis_index("expert") * e_local
+        held = (local >= 0) & (local < e_local)
+        # Tokens routed elsewhere sort past the last local group, where the
+        # grouped GEMM yields zeros for them.
+        sort_idx, _, group_sizes = moe_mod.sort_by_expert(
+            jnp.where(held, local, e_local), e_local + 1)
+        h = kops.grouped_gemm(tokens, wi.astype(tokens.dtype),
+                              group_sizes[:e_local], impl=gemm_impl,
+                              row_index=sort_idx // cfg.top_k)
+        gate, up = jnp.split(h, 2, axis=-1)
+        h = jax.nn.silu(gate) * up
+        ys = kops.grouped_gemm(h, wo.astype(tokens.dtype),
+                               group_sizes[:e_local], impl=gemm_impl,
+                               out_index=sort_idx, out_rows=n * cfg.top_k)
+        y = jnp.einsum("nkd,nk->nd", ys.reshape(n, cfg.top_k, d),
+                       topw.astype(tokens.dtype))
+        return jax.lax.psum(y, "expert")
+
+    experts = P("expert", None, None)
+    return jax.jit(jax.shard_map(
+        local_ffn, mesh=f_mesh,
+        in_specs=(experts, experts, P(), P(), P()), out_specs=P(),
+        check_vma=False))
+
+
+def make_chunk_attention(cfg: ArchConfig, a_mesh: Mesh):
+    """The A role's flash-prefill chunk attention as one program over
+    ``a_mesh``: ``fn(attn_params, x, cache, pos) -> (out, new_cache)``.
+
+    A-role operands are replicated over the mesh. XLA cannot partition a
+    Pallas call, even over replicated operands, so each A device runs the
+    kernel on its own copy inside a ``shard_map``.
+    """
+    rep = P()
+    return jax.jit(jax.shard_map(
+        lambda p, x, cache, pos: attn_mod.attention_prefill_cached(
+            p, cfg, x, cache, pos, impl="pallas"),
+        mesh=a_mesh, in_specs=(rep,) * 4, out_specs=(rep, rep),
+        check_vma=False))
+
+
+def role_devices(devices: Sequence) -> Tuple[list, list]:
+    """(A, F) devices for a flat device list: on one device both roles
+    colocate; on N ≥ 2 the split is N/2 : N − N/2."""
+    devices = list(devices)
+    if len(devices) == 1:
+        return devices, devices
+    half = len(devices) // 2
+    return split_nodes(devices, half, len(devices) - half)
+
+
 def split_nodes(devices: Sequence, n_a_nodes: int, n_f_nodes: int,
                 devices_per_node: int = 1):
     """Split a flat device list into A/F roles at node granularity."""
@@ -395,7 +448,8 @@ def rescale(runtime: AFDRuntime, a_devices: Sequence,
     # Reassemble the original single-program param pytree from the roles.
     cfg = runtime.cfg
     a = jax.device_get(runtime.a_params)
-    f = [None if fl is None else jax.device_get(fl)
+    f = [None if fl is None else
+         {w: jax.device_get(x)[:cfg.n_experts] for w, x in fl.items()}
          for fl in runtime.f_layers]
     layers = []
     for i, lp in enumerate(a["layers"]):

@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.kernels import ops as kops
 
 
@@ -66,7 +64,7 @@ def splitkv_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     dp_size = int(np.prod([mesh.shape[a] for a in other])) if other else 1
     b = (other if len(other) > 1 else (other[0] if other else None)) \
         if (other and q.shape[0] % dp_size == 0) else None
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b, None, None),
                   P(b, axis, None, None),

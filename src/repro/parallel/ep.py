@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 from repro.kernels import ops as kops
 from repro.models import moe as moe_mod
@@ -180,7 +179,7 @@ def moe_ep_train(params, cfg: ArchConfig, x: jax.Array, ep: EPConfig
             aux = jax.lax.pmean(aux, a)
         return out.reshape(x_l.shape), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=ep.mesh,
         in_specs=(P(dp if dp else None, ep.ep_axis, None),
                   P(None, None),
@@ -244,7 +243,7 @@ def moe_ep_decode(params, cfg: ArchConfig, x: jax.Array, ep: EPConfig
         out = _moe_ep_decode_local(xf, router_w, wi_l, wo_l, cfg=cfg, ep=ep)
         return out.reshape(x_l.shape)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=ep.mesh,
         in_specs=(P(dp if dp else None, None, None),
                   P(None, None),
@@ -327,7 +326,7 @@ def moe_ep_decode_etp(params, cfg: ArchConfig, x: jax.Array, ep: EPConfig
             out = jax.lax.all_gather(out, ep.etp_axis, axis=1, tiled=True)
         return out.reshape(x_l.shape)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=ep.mesh,
         in_specs=(P(None, None, None),                    # tokens replicated
                   P(None, None),

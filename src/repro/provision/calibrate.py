@@ -58,7 +58,7 @@ def calibrate(arch: str = "granite-moe-1b-a400m",
     from repro.api import registry
     from repro.core import planner as pln
     from repro.models.model import make_model
-    from repro.parallel.afd import AFDRuntime, split_nodes
+    from repro.parallel.afd import AFDRuntime, role_devices
     from repro.serving.afd_engine import AFDServeEngine, HFUProbe
     from repro.serving.scheduler import SLOConfig, SLOScheduler
     from repro.serving.workload import generate_trace, get_profile
@@ -66,12 +66,7 @@ def calibrate(arch: str = "granite-moe-1b-a400m",
     cfg = configs.get_smoke_config(arch)
     model = make_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    devs = jax.devices()
-    if len(devs) >= 2:
-        half = len(devs) // 2
-        a_dev, f_dev = split_nodes(devs, half, len(devs) - half)
-    else:
-        a_dev = f_dev = [devs[0]]
+    a_dev, f_dev = role_devices(jax.devices())
     rt = AFDRuntime(cfg, params, a_dev, f_dev)
 
     spec = registry.spec_from_arch_config(cfg)

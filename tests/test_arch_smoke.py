@@ -70,7 +70,9 @@ def test_prefill_matches_forward_and_decode_continues(arch):
     params = model.init(jax.random.PRNGKey(0))
     batch = _batch(cfg, jax.random.PRNGKey(1))
     b, s = batch["tokens"].shape
-    logits, _ = model.forward(params, batch)
+    # Serving semantics on both sides: MoE layers route dropless, where
+    # the train forward would drop tokens past an expert's capacity.
+    logits, _ = model.forward(params, batch, mode="prefill")
     max_len = s + (cfg.vision_seq or 0) + 4
     lp, cache = model.prefill(params, batch, max_len=max_len)
     np.testing.assert_allclose(np.asarray(lp), np.asarray(logits[:, -1]),

@@ -53,3 +53,12 @@ def test_serve_driver_afd_two_role():
                           "--xla_force_host_platform_device_count=8"})
     assert "M2N traffic" in out
     assert "AFD: 3 steps" in out
+
+
+def test_serve_driver_afd_one_device():
+    """On one device the A and F roles colocate."""
+    out = _run(["repro.launch.serve", "--arch", "granite-moe-1b-a400m",
+                "--preset", "smoke", "--mode", "afd", "--max-new", "3",
+                "--slots", "2"])
+    assert "M2N traffic" in out
+    assert "AFD: 3 steps" in out

@@ -73,3 +73,21 @@ def test_dtypes(dtype):
        tk=st.sampled_from([8, 32]), seed=st.integers(0, 99))
 def test_hypothesis_sizes(s, tq, tk, seed):
     _run(1, s, 4, 2, 16, tq, tk, seed=seed)
+
+
+def test_chunk_offsets_per_sequence():
+    """Chunked prefill against a live cache with a ragged batch: each
+    sequence's chunk starts at its own position, read from scalar
+    prefetch — one compiled kernel for every chunk start."""
+    from repro.kernels.ref import flash_prefill_ref
+    b, c, t = 3, 8, 48
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(ks[0], (b, c, 4, 16))
+    k = jax.random.normal(ks[1], (b, t, 2, 16))
+    v = jax.random.normal(ks[2], (b, t, 2, 16))
+    off = jnp.asarray([0, 13, 37], jnp.int32)
+    out = flash_prefill_pallas(q, k, v, q_offset=off, t_valid=off + c,
+                               tile_q=8, tile_k=16, interpret=True)
+    ref = flash_prefill_ref(q, k, v, q_offset=off, t_valid=off + c)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
+                               rtol=1e-2)

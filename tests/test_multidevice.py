@@ -11,15 +11,6 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
-from repro import compat
-
-# The subprocess snippets below build meshes with jax ≥ 0.6 axis_types and
-# rely on ≥ 0.6 shard_map semantics across real shards; on 0.4.x they would
-# die with AttributeError inside the child process. Skip cleanly instead.
-pytestmark = pytest.mark.skipif(
-    not compat.HAS_MESH_AXIS_TYPES, reason=compat.JAX_06_SKIP_REASON)
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
@@ -140,6 +131,14 @@ def test_afd_two_role_8dev():
     rt = AFDRuntime(cfg, params, a_dev, f_dev)
     caches, pos = rt.init_cache(B, S + 2)
     out = None
+    for t in range(S):
+        out, caches, pos = rt.decode_step(toks[:, t], caches, pos)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+    # 8 experts over 3 F devices: padded with a zero expert to 3 x 3
+    a_dev, f_dev = split_nodes(jax.devices(), 5, 3)
+    rt = AFDRuntime(cfg, params, a_dev, f_dev)
+    assert rt.f_layers[0]["wi"].shape[0] == 9
+    caches, pos = rt.init_cache(B, S + 2)
     for t in range(S):
         out, caches, pos = rt.decode_step(toks[:, t], caches, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)

@@ -22,8 +22,8 @@ from repro.parallel.afd import AFDRuntime, split_roles
 
 
 def _mesh1():
-    from repro import compat
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _moe_cfg(**kw):
