@@ -1,0 +1,13 @@
+"""Reads of device values on the host per decode tick: the mean over the
+window's decode ticks of the program's ``engine.host_sync`` spans (each
+micro-batch's argmax, each slot's position). Moves ``out_tok_s``."""
+
+from perfbench import progspans
+
+
+def read(view):
+    got = progspans.per_tick(view.served)
+    if got is None:
+        return None
+    per = [sum(s.name == "engine.host_sync" for s in g) for g in got[0]]
+    return sum(per) / len(per) if any(per) else None

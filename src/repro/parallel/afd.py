@@ -38,10 +38,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.kernels import ops as kops
 from repro.models import attention as attn_mod
 from repro.models import kvcache, mamba2, moe as moe_mod
-from repro.models.common import ArchConfig, LayerSpec
+from repro.models.common import ArchConfig
 from repro.models.layers import (apply_lm_head, apply_mlp, apply_norm,
                                  embed_tokens)
 
@@ -157,50 +158,74 @@ class AFDRuntime:
 
     # ---- per-layer A-role pieces -------------------------------------------
 
-    def _mixer(self, lp, spec: LayerSpec, x, cache, pos):
-        cfg = self.cfg
-        h = apply_norm(lp["ln1"], cfg, x)
-        if spec.kind == "attn":
-            mix, nc = attn_mod.attention_decode(lp["attn"], cfg, h, cache,
-                                                pos)
-        else:
-            mix, nc = mamba2.mamba_decode(lp["mamba"], cfg, h, cache)
-        return x + mix, nc
+    def _mixer(self, i: int, mb: int, x, cache, pos):
+        cfg, lp, spec = self.cfg, self.a_params["layers"][i], self.specs[i]
+        with obs.span("afd.attn", layer=i, mb=mb):
+            h = apply_norm(lp["ln1"], cfg, x)
+            if spec.kind == "attn":
+                mix, nc = attn_mod.attention_decode(lp["attn"], cfg, h, cache,
+                                                    pos)
+            else:
+                mix, nc = mamba2.mamba_decode(lp["mamba"], cfg, h, cache)
+            return x + mix, nc
 
-    def _ffn_local(self, lp, spec: LayerSpec, x):
+    def _ffn(self, i: int, mb: int, x):
+        """Layer ``i``'s FFN: the M2N cycle on MoE layers, the A role's
+        dense MLP on the others."""
+        if self.specs[i].moe:
+            return self._moe_cycle(i, mb, x)
+        return self._ffn_local(i, mb, x)
+
+    def _ffn_local(self, i: int, mb: int, x):
         """Dense-MLP layers run wholly on the A role."""
-        cfg = self.cfg
-        if spec.moe or not ("mlp" in lp or cfg.d_ff > 0):
+        cfg, lp = self.cfg, self.a_params["layers"][i]
+        if not ("mlp" in lp or cfg.d_ff > 0):
             return x
-        h = apply_norm(lp["ln2"], cfg, x)
-        return x + apply_mlp(lp["mlp"], cfg, h)
+        with obs.span("afd.dense_ffn", layer=i, mb=mb):
+            h = apply_norm(lp["ln2"], cfg, x)
+            return x + apply_mlp(lp["mlp"], cfg, h)
 
     # ---- the M2N cycle -------------------------------------------------------
 
-    def _moe_cycle(self, lp, f_entry, x):
+    def _moe_cycle(self, i: int, mb: int, x):
         """Norm → route (A) → dispatch → expert FFN (F) → combine (A)."""
-        cfg = self.cfg
-        h = apply_norm(lp["ln2"], cfg, x)
-        tokens = h.reshape(-1, cfg.d_model)
-        _, topw, topi = moe_mod.route(lp["moe"], cfg, tokens)
+        cfg, lp, f_entry = self.cfg, self.a_params["layers"][i], self.f_layers[i]
+        with obs.span("afd.route", layer=i, mb=mb):
+            h = apply_norm(lp["ln2"], cfg, x)
+            tokens = h.reshape(-1, cfg.d_model)
+            _, topw, topi = moe_mod.route(lp["moe"], cfg, tokens)
 
         # dispatch: M2N transfer A → F
-        tok_f = jax.device_put(tokens, self._tok_sharding_f)
-        topw_f = jax.device_put(topw, self._tok_sharding_f)
-        topi_f = jax.device_put(topi, self._tok_sharding_f)
-        self.stats.record(tokens.shape[0], cfg.d_model,
-                          tokens.dtype.itemsize,
-                          topi.size * 4 + topw.size * 4)
+        with obs.span("afd.dispatch", layer=i, mb=mb, rows=tokens.shape[0]):
+            tok_f = jax.device_put(tokens, self._tok_sharding_f)
+            topw_f = jax.device_put(topw, self._tok_sharding_f)
+            topi_f = jax.device_put(topi, self._tok_sharding_f)
+            self.stats.record(tokens.shape[0], cfg.d_model,
+                              tokens.dtype.itemsize,
+                              topi.size * 4 + topw.size * 4)
 
-        routed_f = self._ffn_fn(f_entry["wi"], f_entry["wo"], tok_f,
-                                topw_f, topi_f)
+        with obs.span("afd.ffn", layer=i, mb=mb):
+            routed_f = self._ffn_fn(f_entry["wi"], f_entry["wo"], tok_f,
+                                    topw_f, topi_f)
         # combine: N2M transfer F → A
-        routed = jax.device_put(routed_f, self._tok_sharding_a)
+        with obs.span("afd.combine", layer=i, mb=mb):
+            routed = jax.device_put(routed_f, self._tok_sharding_a)
+            out = x + routed.reshape(x.shape)
+            if "shared" in lp["moe"]:
+                out = out + apply_mlp(lp["moe"]["shared"], cfg, h)
+            return out
 
-        out = x + routed.reshape(x.shape)
-        if "shared" in lp["moe"]:
-            out = out + apply_mlp(lp["moe"]["shared"], cfg, h)
-        return out
+    def _embed(self, mb: int, tokens, positions):
+        with obs.span("afd.embed", mb=mb):
+            return embed_tokens(self.a_params["embed"], self.cfg, tokens,
+                                positions)
+
+    def _head(self, mb: int, x):
+        """Final norm and LM head."""
+        with obs.span("afd.head", mb=mb):
+            x = apply_norm(self.a_params["final_norm"], self.cfg, x)
+            return apply_lm_head(self.a_params["lm_head"],
+                                 self.a_params["embed"], self.cfg, x)
 
     # ---- public decode ---------------------------------------------------------
 
@@ -210,22 +235,13 @@ class AFDRuntime:
 
     def decode_step(self, tokens: jax.Array, caches, pos: jax.Array):
         """One token for one micro-batch. tokens: (B,)."""
-        cfg = self.cfg
-        x = embed_tokens(self.a_params["embed"], cfg, tokens[:, None],
-                         pos[:, None])
+        x = self._embed(0, tokens[:, None], pos[:, None])
         new_caches = []
-        for i, spec in enumerate(self.specs):
-            lp = self.a_params["layers"][i]
-            x, nc = self._mixer(lp, spec, x, caches[i], pos)
-            if spec.moe:
-                x = self._moe_cycle(lp, self.f_layers[i], x)
-            else:
-                x = self._ffn_local(lp, spec, x)
+        for i in range(len(self.specs)):
+            x, nc = self._mixer(i, 0, x, caches[i], pos)
+            x = self._ffn(i, 0, x)
             new_caches.append(nc)
-        x = apply_norm(self.a_params["final_norm"], cfg, x)
-        logits = apply_lm_head(self.a_params["lm_head"],
-                               self.a_params["embed"], cfg, x)
-        return logits[:, 0], new_caches, pos + 1
+        return self._head(0, x)[:, 0], new_caches, pos + 1
 
     def decode_step_3bo(self, micro_batches, n_bo: int = 3):
         """Drive ``n_bo`` micro-batches through the layer loop in the 3BO
@@ -236,79 +252,61 @@ class AFDRuntime:
         micro_batches: list of (tokens (B,), caches, pos). Returns the list
         of (logits, caches, pos).
         """
-        cfg = self.cfg
         states = []
-        for tokens, caches, pos in micro_batches:
-            x = embed_tokens(self.a_params["embed"], cfg, tokens[:, None],
-                             pos[:, None])
+        for m, (tokens, caches, pos) in enumerate(micro_batches):
+            x = self._embed(m, tokens[:, None], pos[:, None])
             states.append({"x": x, "caches": caches, "new": [], "pos": pos})
 
-        for i, spec in enumerate(self.specs):
-            lp = self.a_params["layers"][i]
+        for i in range(len(self.specs)):
             # stage 1: attention for every micro-batch (A role busy)
-            for st in states:
-                st["x"], nc = self._mixer(lp, spec, st["x"], st["caches"][i],
+            for m, st in enumerate(states):
+                st["x"], nc = self._mixer(i, m, st["x"], st["caches"][i],
                                           st["pos"])
                 st["new"].append(nc)
             # stage 2: FFN cycle — dispatches overlap attention of the
             # next micro-batch under async dispatch
-            for st in states:
-                if spec.moe:
-                    st["x"] = self._moe_cycle(lp, self.f_layers[i], st["x"])
-                else:
-                    st["x"] = self._ffn_local(lp, spec, st["x"])
+            for m, st in enumerate(states):
+                st["x"] = self._ffn(i, m, st["x"])
 
-        outs = []
-        for st in states:
-            x = apply_norm(self.a_params["final_norm"], cfg, st["x"])
-            logits = apply_lm_head(self.a_params["lm_head"],
-                                   self.a_params["embed"], cfg, x)
-            outs.append((logits[:, 0], st["new"], st["pos"] + 1))
-        return outs
+        return [(self._head(m, st["x"])[:, 0], st["new"], st["pos"] + 1)
+                for m, st in enumerate(states)]
 
     # ---- public prefill --------------------------------------------------------
 
-    def _mixer_chunk(self, lp, spec: LayerSpec, x, cache, pos,
-                     attn_impl: Optional[str]):
-        cfg = self.cfg
-        h = apply_norm(lp["ln1"], cfg, x)
-        if spec.kind == "attn":
-            if attn_impl == "pallas":
-                mix, nc = self._flash_attn(lp["attn"], h, cache, pos)
-            else:
-                mix, nc = attn_mod.attention_prefill_cached(
-                    lp["attn"], cfg, h, cache, pos)
-            return x + mix, nc
-        # SSM mixers are an O(1)-per-token recurrence with no cached-state
-        # batched form here — step the chunk sequentially (bit-identical to
-        # decode by construction; the M2N win lives in the MoE dispatch).
-        outs = []
-        for j in range(x.shape[1]):
-            mj, cache = mamba2.mamba_decode(lp["mamba"], cfg, h[:, j:j + 1],
-                                            cache)
-            outs.append(mj)
-        return x + jnp.concatenate(outs, axis=1), cache
+    def _mixer_chunk(self, i: int, x, cache, pos, attn_impl: Optional[str]):
+        cfg, lp, spec = self.cfg, self.a_params["layers"][i], self.specs[i]
+        with obs.span("afd.attn", layer=i, mb=0):
+            h = apply_norm(lp["ln1"], cfg, x)
+            if spec.kind == "attn":
+                if attn_impl == "pallas":
+                    mix, nc = self._flash_attn(lp["attn"], h, cache, pos)
+                else:
+                    mix, nc = attn_mod.attention_prefill_cached(
+                        lp["attn"], cfg, h, cache, pos)
+                return x + mix, nc
+            # SSM mixers are an O(1)-per-token recurrence with no cached-state
+            # batched form here — step the chunk sequentially (bit-identical
+            # to decode by construction; the M2N win lives in the MoE
+            # dispatch).
+            outs = []
+            for j in range(x.shape[1]):
+                mj, cache = mamba2.mamba_decode(lp["mamba"], cfg,
+                                                h[:, j:j + 1], cache)
+                outs.append(mj)
+            return x + jnp.concatenate(outs, axis=1), cache
 
     def _prefill_block(self, tokens, caches, pos, attn_impl):
         """One chunk (B, C) through the full layer stack — C tokens per
         M2N cycle instead of 1."""
-        cfg = self.cfg
         c = tokens.shape[1]
-        x = embed_tokens(self.a_params["embed"], cfg, tokens,
-                         pos[:, None] + jnp.arange(c, dtype=pos.dtype))
+        x = self._embed(0, tokens,
+                        pos[:, None] + jnp.arange(c, dtype=pos.dtype))
         new_caches = []
-        for i, spec in enumerate(self.specs):
-            lp = self.a_params["layers"][i]
-            x, nc = self._mixer_chunk(lp, spec, x, caches[i], pos, attn_impl)
-            if spec.moe:
-                x = self._moe_cycle(lp, self.f_layers[i], x)
-            else:
-                x = self._ffn_local(lp, spec, x)
+        for i in range(len(self.specs)):
+            x, nc = self._mixer_chunk(i, x, caches[i], pos, attn_impl)
+            x = self._ffn(i, 0, x)
             new_caches.append(nc)
-        x = apply_norm(self.a_params["final_norm"], cfg, x)
-        logits = apply_lm_head(self.a_params["lm_head"],
-                               self.a_params["embed"], cfg, x)
-        return logits, new_caches, pos + c
+        return self._head(0, x), new_caches, pos + c
 
     def prefill(self, tokens: jax.Array, caches, pos: jax.Array,
                 chunk: Optional[int] = None,

@@ -45,6 +45,7 @@ import collections
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import budget as bdg
 from repro.core import planner as pln
 from repro.core.hardware import HardwareSpec
@@ -182,7 +183,6 @@ class ServeStats:
     arrivals: int = 0
     completed: int = 0
     requeued: int = 0
-    replans: int = 0
 
 
 class AFDServeEngine:
@@ -576,8 +576,9 @@ class AFDServeEngine:
             c = self.prefill_policy.next_chunk(len(pf.req.prompt) - pf.offset)
             blk = jnp.asarray(
                 pf.req.prompt[None, pf.offset:pf.offset + c], jnp.int32)
-            logits, pf.caches, pf.pos = self.rt.prefill(blk, pf.caches,
-                                                        pf.pos)
+            with obs.span("engine.prefill", rid=pf.req.rid, rows=blk.size):
+                logits, pf.caches, pf.pos = self.rt.prefill(blk, pf.caches,
+                                                            pf.pos)
             pf.offset += c
             ran += 1
             self.stats.prefill_tokens += c
@@ -654,7 +655,6 @@ class AFDServeEngine:
                 self.queue.appendleft(req)
                 requeued += 1
         self.stats.requeued += requeued
-        self.stats.replans += 1
         if replan is not None:
             replan(1.0 - frac_nodes_lost)
         return requeued
@@ -687,56 +687,76 @@ class AFDServeEngine:
         """One engine tick: at most one prompt chunk (chunked-prefill mode)
         interleaved with the 3BO decode rotation. Returns the number of
         work units served (decode-live slots + prefill chunks run)."""
-        self._drain_arrivals()
-        self._admit()
-        wall0 = time.perf_counter()
+        with obs.span("engine.tick", tick=self.stats.engine_ticks):
+            self._drain_arrivals()
+            with obs.span("engine.admit"):
+                self._admit()
+            wall0 = time.perf_counter()
 
-        ran_prefill, finished = 0, []
-        if self.prefill_policy is not None and self._prefill_fifo:
-            ran_prefill, finished = self._prefill_tick()
+            ran_prefill, finished = 0, []
+            if self.prefill_policy is not None and self._prefill_fifo:
+                ran_prefill, finished = self._prefill_tick()
 
-        decode_live = self.decode_live_count()
-        if decode_live == 0 and ran_prefill == 0:
-            return 0
+            decode_live = self.decode_live_count()
+            if decode_live == 0 and ran_prefill == 0:
+                return 0
 
-        outs = None
-        if decode_live:
-            outs = self.rt.decode_step_3bo(
-                [(jnp.asarray(mb.tokens), mb.caches, mb.pos)
-                 for mb in self.mbs], n_bo=self.n_bo)
+            outs = None
+            if decode_live:
+                with obs.span("engine.decode"):
+                    outs = self.rt.decode_step_3bo(
+                        [(jnp.asarray(mb.tokens), mb.caches, mb.pos)
+                         for mb in self.mbs], n_bo=self.n_bo)
 
-        dt = self._tick_duration(wall0)
-        self.now += dt
-        if self.scheduler is not None:
-            self.scheduler.observe(dt)
+            dt = self._tick_duration(wall0)
+            self.now += dt
+            if self.scheduler is not None:
+                self.scheduler.observe(dt)
 
-        if outs is not None:
-            for mb, (logits, caches, pos) in zip(self.mbs, outs):
-                mb.caches, mb.pos = caches, pos
+            if outs is not None:
+                with obs.span("engine.sample"):
+                    self._sample(outs)
+                self.stats.decode_ticks += 1
+                self._w_decode_ticks += 1
+
+            # Prefills that finished this tick: splice + first token now, so
+            # t_first lands in the tick the logits were produced.
+            for mb_i, slot, logits in finished:
+                self._finish_prefill(mb_i, slot, logits)
+
+            self.stats.engine_ticks += 1
+            self._w_ticks += 1
+            if self._w_ticks >= self.window_ticks:
+                self._close_window()
+            return decode_live + ran_prefill
+
+    def _sample(self, outs) -> None:
+        """Take each decoding slot's next token from its micro-batch's
+        logits, and complete the requests that are done. Every read of a
+        device value on the host is an ``engine.host_sync`` span."""
+        for mb_i, (mb, (logits, caches, pos)) in enumerate(zip(self.mbs,
+                                                                outs)):
+            mb.caches, mb.pos = caches, pos
+            with obs.span("engine.host_sync", mb=mb_i):
                 nxt = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
-                for i in mb.live():
-                    req = mb.slots[i]
-                    tok = (int(nxt[i]) if self.greedy
-                           else self._select(logits[i]))
-                    req.output.append(tok)
-                    mb.tokens[i] = tok
-                    self.stats.tokens_out += 1
-                    self._w_tokens_out += 1
-                    if req.done or int(mb.pos[i]) >= self.max_len - 1:
-                        self._complete(mb, i)
-            self.stats.decode_ticks += 1
-            self._w_decode_ticks += 1
-
-        # Prefills that finished this tick: splice + first token now, so
-        # t_first lands in the tick the logits were produced.
-        for mb_i, slot, logits in finished:
-            self._finish_prefill(mb_i, slot, logits)
-
-        self.stats.engine_ticks += 1
-        self._w_ticks += 1
-        if self._w_ticks >= self.window_ticks:
-            self._close_window()
-        return decode_live + ran_prefill
+            for i in mb.live():
+                req = mb.slots[i]
+                if self.greedy:
+                    tok = int(nxt[i])
+                else:
+                    with obs.span("engine.host_sync", mb=mb_i):
+                        tok = self._select(logits[i])
+                req.output.append(tok)
+                mb.tokens[i] = tok
+                self.stats.tokens_out += 1
+                self._w_tokens_out += 1
+                if req.done:
+                    self._complete(mb, i)
+                    continue
+                with obs.span("engine.host_sync", mb=mb_i):
+                    at_end = int(mb.pos[i]) >= self.max_len - 1
+                if at_end:
+                    self._complete(mb, i)
 
     # ---- the serve loop ----------------------------------------------------
 

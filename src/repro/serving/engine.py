@@ -110,7 +110,6 @@ class EngineStats:
     tokens_out: int = 0
     prefills: int = 0
     requeued: int = 0
-    replans: int = 0
 
     def throughput(self, wall: float) -> float:
         return self.tokens_out / wall if wall > 0 else 0.0
@@ -236,7 +235,6 @@ class DecodeEngine:
             drained = jnp.arange(n_drain)
             self.cache["pos"] = self.cache["pos"].at[drained].set(0)
         self.stats.requeued += requeued
-        self.stats.replans += 1
         if replan is not None:
             replan(1.0 - frac_nodes_lost)
         return requeued
