@@ -12,7 +12,8 @@ cell, a configuration or a mix.
 The timed entry is ``AFDServeEngine.tick()``, driven here one tick at a time
 with ``tick_seconds=None``. The host clock is read before the first timed
 tick and after each tick, once the tick's outputs are ready. The window
-closes at the end of the first tick that ends at or after ``--seconds``.
+closes at the end of the first tick that ends at or after ``--seconds``
+(or, where ``serve`` is given ``n_ticks``, after that many ticks).
 """
 
 from __future__ import annotations
@@ -200,10 +201,13 @@ class Served:
 
 
 def serve(cell: Cell, seed: int, seconds: float, devices, *,
-          trace: bool = False, compiles: Optional[CompileCounter] = None
-          ) -> Served:
+          trace: bool = False, compiles: Optional[CompileCounter] = None,
+          n_ticks: Optional[int] = None) -> Served:
     """Build the system from the seed, fill it, warm it, and time whole
-    ticks for ``seconds``. Leaves no device state behind when it returns."""
+    ticks for ``seconds``. With ``n_ticks`` the window is that many ticks
+    and the clock does not close it, so that what it serves depends on the
+    seed alone, not on the machine's speed (the CPU tests' tiny cell).
+    Leaves no device state behind when it returns."""
     import jax
     from repro.parallel.afd import AFDRuntime, role_devices
     from repro.serving.afd_engine import AFDServeEngine, ServeRequest
@@ -311,7 +315,8 @@ def serve(cell: Cell, seed: int, seconds: float, devices, *,
     ticks = []
     while True:
         ticks.append(step())
-        if ticks[-1].t1 - t_open >= seconds:
+        if (len(ticks) >= n_ticks if n_ticks is not None
+                else ticks[-1].t1 - t_open >= seconds):
             break
     t_close = ticks[-1].t1
     span.__exit__(None, None, None)
@@ -505,9 +510,9 @@ def breakdown(view: RunView) -> Dict:
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         t_start: float, compiles: Optional[CompileCounter] = None,
-        log=print) -> Dict:
+        log=print, n_ticks: Optional[int] = None) -> Dict:
     served = serve(cell, seed, seconds, devices, trace=trace,
-                   compiles=compiles)
+                   compiles=compiles, n_ticks=n_ticks)
     setup_s = served.t_open - t_start
     device = device_record(devices)
     e2e = end_to_end(served, setup_s)

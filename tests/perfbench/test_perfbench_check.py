@@ -1,7 +1,11 @@
 """The check that decides a run's ``correct``, driven through the rest of a
 run at a tiny size on the CPU (the look for a chip is skipped): a sound run
 passes, the fp8 control fails, and so does each fault the decode cells can
-have, planted in the timed path."""
+have, planted in the timed path.
+
+The tiny window is bounded by ticks, not by the clock, and holds every
+request of the mix to its end: the check then compares every token the
+mix asks for, the same tokens for a seed on a machine of any speed."""
 
 import json
 import os
@@ -19,24 +23,29 @@ from perfbench.traffic import generator  # noqa: E402
 from repro.parallel import afd  # noqa: E402
 
 # Limits of the tiny cell, set like the chip cells' from readings on the CPU
-# over 12 seeds: the mean served-token gap of sound runs read at most 0.00935
-# standard deviations, the fp8 control's at least 0.0151 (PERF.md).
-TINY_LIMITS = {"m2n_bytes_off": 0, "served_gap_mean_std": 0.012}
+# over 12 seeds, on the program and on a copy with its A role compiled: the
+# mean served-token gap of sound runs read at most 0.00259 standard
+# deviations, the fp8 control's at least 0.0215 (PERF.md).
+TINY_LIMITS = {"m2n_bytes_off": 0, "served_gap_mean_std": 0.008}
 SEED = 2**33 + 5
+# Ticks in the tiny window: every request of the mix has finished by the
+# 93rd on every seed tried (PERF.md); the ticks after the last are empty.
+TINY_TICKS = 160
 
 
 def tiny_cell():
     """``granite-decode`` at a size the CPU runs in seconds: the Granite
-    configuration with narrow widths (32 experts, top 8, as published) and
-    four decode slots."""
+    configuration with narrow widths (32 experts, top 8, as published), eight
+    decode slots and three blocks of requests, every served token
+    compared."""
     conf = json.load(open(os.path.join(harness.BENCH, "configs",
                                        "granite-moe-1b-a400m.json")))
     conf.update(hidden_size=128, intermediate_size=32, num_hidden_layers=2,
                 num_attention_heads=4, num_key_value_heads=2,
                 vocab_size=1024)
     mix = generator.load("decode-long-out")
-    mix.update(n_bo=2, mb_slots=2, max_len=96, prefill_chunk=16,
-               prefill_chunks_per_tick=4, check_tokens=24)
+    mix.update(n_bo=2, mb_slots=4, blocks=3, max_len=96, prefill_chunk=16,
+               prefill_chunks_per_tick=4, check_tokens=10**6)
     mix["prompt"] = {"buckets": [8, 16]}
     mix["output"] = {"lo": 12, "hi": 40}
     mix["in_flight"] = {"reserve": 8, "step": 8}
@@ -49,26 +58,58 @@ def tiny_cell():
 
 
 def run_once(cell, seed=SEED):
-    res = harness.run(cell, seed, 1.0, False, jax.devices()[:1],
-                      time.perf_counter(), log=lambda *a, **k: None)
+    res = harness.run(cell, seed, 0.0, False, jax.devices()[:1],
+                      time.perf_counter(), log=lambda *a, **k: None,
+                      n_ticks=TINY_TICKS)
     return res
 
 
+def mix_requests(cell, seed=SEED):
+    return generator.requests(cell.traffic, seed, cell.config["vocab_size"])
+
+
+def mix_tokens(cell, seed=SEED):
+    """Every token the mix asks for."""
+    return sum(r.max_new_tokens for r in mix_requests(cell, seed))
+
+
+@pytest.fixture(scope="module")
+def served_tiny():
+    cell = tiny_cell()
+    return cell, harness.serve(cell, SEED, 0.0, jax.devices()[:1],
+                               n_ticks=TINY_TICKS)
+
+
 def test_sound_run_is_correct():
-    res = run_once(tiny_cell())
+    cell = tiny_cell()
+    res = run_once(cell)
     assert res["correct"], res["checks"]
     assert res["checks"]["m2n_dispatch_bytes_off"]["value"] == 0
-    assert res["checks"]["tokens_compared"]["value"] >= 24
+    assert res["checks"]["tokens_compared"]["value"] == mix_tokens(cell)
     assert set(res["metrics"]) == {"out_tok_s", "itl_p95_ms", "setup_s"}
     assert list(res)[-1] == "checks"
-    assert res["attempted"] >= 4 and res["failed"] == 0
+    assert res["attempted"] == len(mix_requests(cell))
+    assert res["failed"] == 0
 
 
-def test_fp8_control_fails_the_limit():
-    cell = tiny_cell()
-    sv = harness.serve(cell, SEED, 1.0, jax.devices()[:1])
+def test_fp8_control_fails_the_limit(served_tiny):
+    cell, sv = served_tiny
     assert harness.passed(harness.check(cell, sv, SEED))
     assert not harness.passed(harness.check(cell, sv, SEED, control=True))
+
+
+def test_check_does_not_depend_on_the_window(served_tiny):
+    """A window twice as many ticks long compares the same tokens and reads
+    the same gap; neither window's ``seconds`` (0 and 10**6) is read."""
+    cell, sv = served_tiny
+    longer = harness.serve(cell, SEED, 10**6, jax.devices()[:1],
+                           n_ticks=2 * TINY_TICKS)
+    assert len(longer.ticks) == 2 * len(sv.ticks)
+    a, b = (harness.check(cell, s, SEED) for s in (sv, longer))
+    assert a["tokens_compared"]["value"] == mix_tokens(cell)
+    for name in ("tokens_compared", "served_gap_mean_std",
+                 "m2n_dispatch_bytes_off"):
+        assert a[name]["value"] == b[name]["value"], name
 
 
 def _stale_state(monkeypatch):

@@ -134,6 +134,20 @@ def test_clock_alignment_within_a_millisecond(recorded):
     assert harness.read_metric("host_syncs.decode", view) == 3.0
 
 
+def test_host_syncs_reads_zero_without_reads(recorded):
+    """Decode ticks with program spans and no host read read 0, not
+    nothing; a ring that no longer holds the window still reads nothing."""
+    spans, view = _run()
+    no_reads = [s for s in spans if s.name != "engine.host_sync"]
+    recorded(no_reads)
+    assert harness.read_metric("host_syncs.decode", view) == 0.0
+    recorded(no_reads[1:])
+    assert harness.read_metric("host_syncs.decode", view) is None
+    # decode ticks that hold no program span cannot be read
+    recorded([s for s in spans if s.ids.get("tick", 0) < 0])
+    assert harness.read_metric("host_syncs.decode", view) is None
+
+
 def test_innermost_segments():
     rec = _Spans()
     a = rec.add("a", 0, 100)
@@ -165,17 +179,25 @@ def test_wrapped_ring_or_no_spans_reads_nothing(recorded, monkeypatch):
 
 
 def test_host_readers_on_a_tiny_run():
-    """The served program's own spans, on the CPU: every window decode tick
-    reads each micro-batch's argmax and each live slot's position."""
+    """The served program's own spans, on the CPU: the reader gives the
+    mean count of ``engine.host_sync`` spans per window decode tick,
+    counted here from the recorded spans: at most a read of each
+    micro-batch's argmax and of each slot's position, and as few as none."""
     from test_perfbench_check import SEED, tiny_cell
     cell = tiny_cell()
-    served = harness.serve(cell, SEED, 1.0, jax.devices()[:1])
+    served = harness.serve(cell, SEED, 0.0, jax.devices()[:1], n_ticks=6)
     view = harness.RunView(cell=cell, served=served, arch=None, peak={},
                            planes=[])
     mix = cell.traffic
     syncs = harness.read_metric("host_syncs.decode", view)
-    assert int(mix["n_bo"]) < syncs <= int(mix["n_bo"]) * (
-        1 + int(mix["mb_slots"]))
+    ticks = [(round(t.t0 * 1e9), round(t.t1 * 1e9)) for t in served.ticks
+             if t.decode_tokens and not t.prefill_tokens]
+    assert ticks
+    reads = [s for s in obs.spans() if s.name == "engine.host_sync"]
+    per = [sum(a <= s.t0_ns and s.t1_ns <= b for s in reads)
+           for a, b in ticks]
+    assert syncs == pytest.approx(sum(per) / len(per))
+    assert 0 <= syncs <= int(mix["n_bo"]) * (1 + int(mix["mb_slots"]))
     for name in ("a_role_host_ms.decode", "afd_host_ms.decode",
                  "sample_host_ms.decode"):
         assert harness.read_metric(name, view) > 0, name
