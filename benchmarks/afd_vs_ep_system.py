@@ -52,7 +52,7 @@ def main() -> None:
     rt = AFDRuntime(cfg, params, a_dev, f_dev)
     caches, pos = rt.init_cache(B, 64)
     t = toks0
-    rt.decode_step(t, caches, pos)              # warm (caches unchanged refs)
+    rt.decode_step(t, caches, pos)              # warm (consumes the caches)
     caches, pos = rt.init_cache(B, 64)
     t0 = time.perf_counter()
     for _ in range(steps):

@@ -16,7 +16,7 @@ q is reshaped to (B, S, n_kv, group, d_head) and contracted against
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -248,19 +248,21 @@ def set_decode_attention_override(fn) -> None:
 
 
 def attention_decode(params, cfg: ArchConfig, x: jax.Array,
-                     cache: Dict[str, jax.Array], pos: jax.Array
+                     cache: Dict[str, jax.Array], pos: jax.Array,
+                     write_kv: Callable = kvcache.write_kv
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token step. x: (B, 1, D); pos: (B,) current absolute positions.
 
     Keys carry RoPE at their absolute positions (applied at write time), so
-    ring-buffer eviction needs no re-rotation.
+    ring-buffer eviction needs no re-rotation. ``write_kv`` writes the new
+    k/v into the cache (``kvcache.write_kv`` or ``kvcache.write_kv_rows``).
     """
     q = _project_q(params, cfg, x)
     k_new, v_new = _project_kv(params, cfg, x)
     if cfg.use_rope:
         q = apply_rope(q, pos[:, None], cfg.rope_theta)
         k_new = apply_rope(k_new, pos[:, None], cfg.rope_theta)
-    new_cache = kvcache.write_kv(cfg, cache, k_new, v_new, pos)
+    new_cache = write_kv(cfg, cache, k_new, v_new, pos)
     if _DECODE_OVERRIDE is not None:
         out = _DECODE_OVERRIDE(cfg, q, new_cache["k"], new_cache["v"], pos)
         if out is not None:

@@ -110,6 +110,32 @@ def write_kv(cfg: ArchConfig, cache: Dict[str, jax.Array],
     return {"k": k, "v": v}
 
 
+def write_kv_rows(cfg: ArchConfig, cache: Dict[str, jax.Array],
+                  k_new: jax.Array, v_new: jax.Array,
+                  pos: jax.Array) -> Dict[str, jax.Array]:
+    """``write_kv`` as one update slice per sequence, for a program that
+    owns a donated, unsharded cache (the AFD runtime's A role).
+
+    The TPU lays a cache whose head is narrower than 128 lanes out with the
+    positions minor; a scatter there copies the whole cache out of that
+    layout and back, where an update slice writes in place. The B slices
+    are unrolled, so the program grows with the batch.
+
+    A position past a full cache is clamped into its own row's last slot
+    (``write_kv`` drops it). Only a row already past its cache reads that
+    slot afterwards: any other row writes slot T-1 at position T-1 before
+    it reads it.
+    """
+    t = cache["k"].shape[1]
+    slot = pos % t if cfg.sliding_window is not None else pos
+    k, v = cache["k"], cache["v"]
+    for b in range(k_new.shape[0]):
+        at = (b, slot[b], 0, 0)
+        k = jax.lax.dynamic_update_slice(k, k_new[b:b + 1].astype(k.dtype), at)
+        v = jax.lax.dynamic_update_slice(v, v_new[b:b + 1].astype(v.dtype), at)
+    return {"k": k, "v": v}
+
+
 def write_kv_chunk(cfg: ArchConfig, cache: Dict[str, jax.Array],
                    k_new: jax.Array, v_new: jax.Array,
                    pos: jax.Array) -> Dict[str, jax.Array]:

@@ -27,12 +27,12 @@ spans of the last tick, each with its duration:
         if s.ids.get("tick") == tick:
             print(s.name, s.ids, (s.t1_ns - s.t0_ns) / 1e6, "ms")
 
-The ring keeps the newest ``RING_SPANS`` (65,536) spans: a served
+The ring keeps the newest ``RING_SPANS`` (262,144) spans: a served
 Granite-MoE decode tick writes about 420 spans (24 layers, 3 micro-batches,
 a host read per slot), a two-layer Mixtral tick about 90, so the ring holds
-the last 150 and 700 ticks. A recorded span takes about 380 bytes of host
-memory (the tuple, its two times and its ids), so a full ring holds about
-25 MB, and never more.
+the last 620 and 2,900 ticks, a 51 s window of ticks down to 82 ms. A
+recorded span takes about 380 bytes of host memory (the tuple, its two
+times and its ids), so a full ring holds about 100 MB, and never more.
 
 Compiles: a ``jax.monitoring`` listener records each program JAX compiles,
 or loads from its persistent compilation cache, as a ``jax.compile`` span
@@ -52,7 +52,7 @@ from typing import Dict, List, NamedTuple, Optional
 import jax
 from jax import monitoring
 
-RING_SPANS = 1 << 16
+RING_SPANS = 1 << 18
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 

@@ -20,6 +20,13 @@ with the rotation schedule of §2.2 — on real hardware JAX's async dispatch
 overlaps the three resources; on CPU the schedule is validated structurally
 and by the byte accounting, while core/overlap.py prices the timing.
 
+The A role runs as compiled programs, one per span body (``afd.attn``,
+``afd.route``, the A side of ``afd.combine``, ``afd.dense_ffn``,
+``afd.embed``, ``afd.head``). Each takes the layer's weights and cache as
+arguments, so one program serves every layer of a kind; the mixers donate
+the cache, whose write then lands in place. The M2N transfers between them
+stay ``device_put``s.
+
 The runtime tracks dispatch/combine bytes per micro-batch so the system
 benchmark can check them against Eq. 9's B_rank prediction.
 
@@ -31,6 +38,7 @@ split; the planner reports it instead).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -87,7 +95,7 @@ def split_roles(params, cfg: ArchConfig):
 
 
 # ---------------------------------------------------------------------------
-# Runtime
+# M2N counters
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass
@@ -119,8 +127,112 @@ class AFDStats:
             tokens_routed=self.tokens_routed - prev.tokens_routed)
 
 
+# ---------------------------------------------------------------------------
+# A-role programs
+# ---------------------------------------------------------------------------
+#
+# The architecture is a static argument and a layer's weights and cache are
+# arguments, so all layers of a kind share one compiled program per shape:
+# (B, 1) in decode, (B, C) in a prefill chunk.
+
+_a_program = functools.partial(jax.jit, static_argnames=("cfg",))
+_a_mixer = functools.partial(jax.jit, static_argnames=("cfg",),
+                             donate_argnames=("cache",))
+
+
+@_a_mixer
+def mix_attn(cfg: ArchConfig, ln1, p, x, cache, pos):
+    """Decode ``afd.attn`` on an attention layer: ``ln1``, one-token
+    attention with its cache write (an update slice per sequence, in place
+    in the donated cache), residual."""
+    mix, cache = attn_mod.attention_decode(p, cfg, apply_norm(ln1, cfg, x),
+                                           cache, pos,
+                                           write_kv=kvcache.write_kv_rows)
+    return x + mix, cache
+
+
+@_a_mixer
+def mix_mamba(cfg: ArchConfig, ln1, p, x, cache, pos):
+    """``afd.attn`` on a Mamba layer, one token: ``ln1``, one recurrent
+    step, residual (``pos`` is unused; the state carries the position)."""
+    mix, cache = mamba2.mamba_decode(p, cfg, apply_norm(ln1, cfg, x), cache)
+    return x + mix, cache
+
+
+def _attn_chunk(cfg: ArchConfig, impl: Optional[str], ln1, p, x, cache, pos):
+    mix, cache = attn_mod.attention_prefill_cached(
+        p, cfg, apply_norm(ln1, cfg, x), cache, pos, impl=impl)
+    return x + mix, cache
+
+
+@_a_mixer
+def mix_attn_chunk(cfg: ArchConfig, ln1, p, x, cache, pos):
+    """Chunk ``afd.attn`` on an attention layer, dense masked: ``ln1``,
+    ``attention_prefill_cached``, residual."""
+    return _attn_chunk(cfg, None, ln1, p, x, cache, pos)
+
+
+# Decode mixer by ``LayerSpec.kind``, which is also the key of the layer's
+# mixer weights.
+MIXERS = {"attn": mix_attn, "mamba": mix_mamba}
+
+
+@_a_program
+def route_tokens(cfg: ArchConfig, ln2, moe, x):
+    """``afd.route``: ``ln2`` and top-k routing of the flattened tokens.
+    Returns (tokens (N, D), weights (N, k), expert ids (N, k))."""
+    tokens = apply_norm(ln2, cfg, x).reshape(-1, cfg.d_model)
+    _, topw, topi = moe_mod.route(moe, cfg, tokens)
+    return tokens, topw, topi
+
+
+@_a_program
+def combine_routed(cfg: ArchConfig, shared, x, routed, tokens):
+    """The A side of ``afd.combine``: residual plus the routed experts'
+    output, plus the shared experts (``shared`` None without them) on the
+    normed tokens."""
+    out = x + routed.reshape(x.shape)
+    if shared is not None:
+        out = out + apply_mlp(shared, cfg, tokens.reshape(x.shape))
+    return out
+
+
+@_a_program
+def dense_ffn(cfg: ArchConfig, ln2, mlp, x):
+    """``afd.dense_ffn``: ``ln2``, dense MLP, residual."""
+    return x + apply_mlp(mlp, cfg, apply_norm(ln2, cfg, x))
+
+
+@_a_program
+def embed(cfg: ArchConfig, embed_p, tokens, pos):
+    """``afd.embed``: tokens (B,) or (B, C) from positions ``pos`` (B,)."""
+    if tokens.ndim == 1:
+        tokens = tokens[:, None]
+    positions = pos[:, None] + jnp.arange(tokens.shape[1], dtype=pos.dtype)
+    return embed_tokens(embed_p, cfg, tokens, positions)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "squeeze"))
+def head(cfg: ArchConfig, final_norm, lm_head, embed_p, x, pos,
+         squeeze: bool = False):
+    """``afd.head``: final norm and LM head over x (B, S, D). Returns the
+    logits ((B, V) with ``squeeze``, else (B, S, V)) and ``pos + S``."""
+    logits = apply_lm_head(lm_head, embed_p, cfg,
+                           apply_norm(final_norm, cfg, x))
+    return (logits[:, 0] if squeeze else logits), pos + x.shape[1]
+
+
+# ---------------------------------------------------------------------------
+# Runtime
+# ---------------------------------------------------------------------------
+
 class AFDRuntime:
-    """Two-role decode runtime. Devices are split at node granularity."""
+    """Two-role decode runtime. Devices are split at node granularity.
+
+    Caches are donated: each step writes every layer's new cache into the
+    list it was given, in place, and returns that list. An array taken
+    from the list before the call is deleted by it and must not be read.
+    """
 
     def __init__(self, cfg: ArchConfig, params, a_devices: Sequence,
                  f_devices: Sequence, gemm_impl: Optional[str] = None):
@@ -159,15 +271,9 @@ class AFDRuntime:
     # ---- per-layer A-role pieces -------------------------------------------
 
     def _mixer(self, i: int, mb: int, x, cache, pos):
-        cfg, lp, spec = self.cfg, self.a_params["layers"][i], self.specs[i]
+        lp, kind = self.a_params["layers"][i], self.specs[i].kind
         with obs.span("afd.attn", layer=i, mb=mb):
-            h = apply_norm(lp["ln1"], cfg, x)
-            if spec.kind == "attn":
-                mix, nc = attn_mod.attention_decode(lp["attn"], cfg, h, cache,
-                                                    pos)
-            else:
-                mix, nc = mamba2.mamba_decode(lp["mamba"], cfg, h, cache)
-            return x + mix, nc
+            return MIXERS[kind](self.cfg, lp["ln1"], lp[kind], x, cache, pos)
 
     def _ffn(self, i: int, mb: int, x):
         """Layer ``i``'s FFN: the M2N cycle on MoE layers, the A role's
@@ -182,8 +288,7 @@ class AFDRuntime:
         if not ("mlp" in lp or cfg.d_ff > 0):
             return x
         with obs.span("afd.dense_ffn", layer=i, mb=mb):
-            h = apply_norm(lp["ln2"], cfg, x)
-            return x + apply_mlp(lp["mlp"], cfg, h)
+            return dense_ffn(cfg, lp["ln2"], lp["mlp"], x)
 
     # ---- the M2N cycle -------------------------------------------------------
 
@@ -191,9 +296,7 @@ class AFDRuntime:
         """Norm → route (A) → dispatch → expert FFN (F) → combine (A)."""
         cfg, lp, f_entry = self.cfg, self.a_params["layers"][i], self.f_layers[i]
         with obs.span("afd.route", layer=i, mb=mb):
-            h = apply_norm(lp["ln2"], cfg, x)
-            tokens = h.reshape(-1, cfg.d_model)
-            _, topw, topi = moe_mod.route(lp["moe"], cfg, tokens)
+            tokens, topw, topi = route_tokens(cfg, lp["ln2"], lp["moe"], x)
 
         # dispatch: M2N transfer A → F
         with obs.span("afd.dispatch", layer=i, mb=mb, rows=tokens.shape[0]):
@@ -210,38 +313,40 @@ class AFDRuntime:
         # combine: N2M transfer F → A
         with obs.span("afd.combine", layer=i, mb=mb):
             routed = jax.device_put(routed_f, self._tok_sharding_a)
-            out = x + routed.reshape(x.shape)
-            if "shared" in lp["moe"]:
-                out = out + apply_mlp(lp["moe"]["shared"], cfg, h)
-            return out
+            return combine_routed(cfg, lp["moe"].get("shared"), x, routed,
+                                  tokens)
 
-    def _embed(self, mb: int, tokens, positions):
+    def _embed(self, mb: int, tokens, pos):
         with obs.span("afd.embed", mb=mb):
-            return embed_tokens(self.a_params["embed"], self.cfg, tokens,
-                                positions)
+            return embed(self.cfg, self.a_params["embed"], tokens, pos)
 
-    def _head(self, mb: int, x):
-        """Final norm and LM head."""
+    def _head(self, mb: int, x, pos, squeeze: bool = False):
+        """Final norm and LM head; the positions advance past x."""
+        ap = self.a_params
         with obs.span("afd.head", mb=mb):
-            x = apply_norm(self.a_params["final_norm"], self.cfg, x)
-            return apply_lm_head(self.a_params["lm_head"],
-                                 self.a_params["embed"], self.cfg, x)
+            return head(self.cfg, ap["final_norm"], ap["lm_head"],
+                        ap["embed"], x, pos, squeeze=squeeze)
 
     # ---- public decode ---------------------------------------------------------
 
     def init_cache(self, batch: int, max_len: int):
-        return [kvcache.init_layer_cache(self.cfg, s, batch, max_len)
-                for s in self.specs], jnp.zeros((batch,), jnp.int32)
+        """Per-layer caches and positions (B,), placed on the A role."""
+        caches = [kvcache.init_layer_cache(self.cfg, s, batch, max_len)
+                  for s in self.specs]
+        return jax.device_put((caches, jnp.zeros((batch,), jnp.int32)),
+                              self._tok_sharding_a)
 
     def decode_step(self, tokens: jax.Array, caches, pos: jax.Array):
-        """One token for one micro-batch. tokens: (B,)."""
-        x = self._embed(0, tokens[:, None], pos[:, None])
-        new_caches = []
+        """One token for one micro-batch. tokens: (B,). ``caches`` is
+        consumed: its arrays are donated and the list is updated in place
+        to the new caches, which are returned with the logits and pos + 1.
+        """
+        x = self._embed(0, tokens, pos)
         for i in range(len(self.specs)):
-            x, nc = self._mixer(i, 0, x, caches[i], pos)
+            x, caches[i] = self._mixer(i, 0, x, caches[i], pos)
             x = self._ffn(i, 0, x)
-            new_caches.append(nc)
-        return self._head(0, x)[:, 0], new_caches, pos + 1
+        logits, pos = self._head(0, x, pos, squeeze=True)
+        return logits, caches, pos
 
     def decode_step_3bo(self, micro_batches, n_bo: int = 3):
         """Drive ``n_bo`` micro-batches through the layer loop in the 3BO
@@ -250,63 +355,58 @@ class AFDRuntime:
         the A role — JAX async dispatch realises the overlap on hardware.
 
         micro_batches: list of (tokens (B,), caches, pos). Returns the list
-        of (logits, caches, pos).
+        of (logits, caches, pos). Each micro-batch's ``caches`` is consumed:
+        its arrays are donated and the list is updated in place to the new
+        caches.
         """
-        states = []
-        for m, (tokens, caches, pos) in enumerate(micro_batches):
-            x = self._embed(m, tokens[:, None], pos[:, None])
-            states.append({"x": x, "caches": caches, "new": [], "pos": pos})
+        xs = [self._embed(m, tokens, pos)
+              for m, (tokens, _, pos) in enumerate(micro_batches)]
 
         for i in range(len(self.specs)):
             # stage 1: attention for every micro-batch (A role busy)
-            for m, st in enumerate(states):
-                st["x"], nc = self._mixer(i, m, st["x"], st["caches"][i],
-                                          st["pos"])
-                st["new"].append(nc)
+            for m, (_, caches, pos) in enumerate(micro_batches):
+                xs[m], caches[i] = self._mixer(i, m, xs[m], caches[i], pos)
             # stage 2: FFN cycle — dispatches overlap attention of the
             # next micro-batch under async dispatch
-            for m, st in enumerate(states):
-                st["x"] = self._ffn(i, m, st["x"])
+            for m in range(len(xs)):
+                xs[m] = self._ffn(i, m, xs[m])
 
-        return [(self._head(m, st["x"])[:, 0], st["new"], st["pos"] + 1)
-                for m, st in enumerate(states)]
+        outs = []
+        for m, (_, caches, pos) in enumerate(micro_batches):
+            logits, pos = self._head(m, xs[m], pos, squeeze=True)
+            outs.append((logits, caches, pos))
+        return outs
 
     # ---- public prefill --------------------------------------------------------
 
     def _mixer_chunk(self, i: int, x, cache, pos, attn_impl: Optional[str]):
-        cfg, lp, spec = self.cfg, self.a_params["layers"][i], self.specs[i]
+        cfg, lp, kind = self.cfg, self.a_params["layers"][i], self.specs[i].kind
         with obs.span("afd.attn", layer=i, mb=0):
-            h = apply_norm(lp["ln1"], cfg, x)
-            if spec.kind == "attn":
+            if kind == "attn":
                 if attn_impl == "pallas":
-                    mix, nc = self._flash_attn(lp["attn"], h, cache, pos)
-                else:
-                    mix, nc = attn_mod.attention_prefill_cached(
-                        lp["attn"], cfg, h, cache, pos)
-                return x + mix, nc
+                    return self._flash_attn(lp["ln1"], lp["attn"], x, cache,
+                                            pos)
+                return mix_attn_chunk(cfg, lp["ln1"], lp["attn"], x, cache,
+                                      pos)
             # SSM mixers are an O(1)-per-token recurrence with no cached-state
-            # batched form here — step the chunk sequentially (bit-identical
-            # to decode by construction; the M2N win lives in the MoE
-            # dispatch).
+            # batched form here — step the chunk through the decode program
+            # token by token (bit-identical to decode by construction; the
+            # M2N win lives in the MoE dispatch).
             outs = []
             for j in range(x.shape[1]):
-                mj, cache = mamba2.mamba_decode(lp["mamba"], cfg,
-                                                h[:, j:j + 1], cache)
-                outs.append(mj)
-            return x + jnp.concatenate(outs, axis=1), cache
+                y, cache = mix_mamba(cfg, lp["ln1"], lp["mamba"],
+                                     x[:, j:j + 1], cache, pos)
+                outs.append(y)
+            return jnp.concatenate(outs, axis=1), cache
 
     def _prefill_block(self, tokens, caches, pos, attn_impl):
         """One chunk (B, C) through the full layer stack — C tokens per
-        M2N cycle instead of 1."""
-        c = tokens.shape[1]
-        x = self._embed(0, tokens,
-                        pos[:, None] + jnp.arange(c, dtype=pos.dtype))
-        new_caches = []
+        M2N cycle instead of 1. Returns (logits, pos + C)."""
+        x = self._embed(0, tokens, pos)
         for i in range(len(self.specs)):
-            x, nc = self._mixer_chunk(i, x, caches[i], pos, attn_impl)
+            x, caches[i] = self._mixer_chunk(i, x, caches[i], pos, attn_impl)
             x = self._ffn(i, 0, x)
-            new_caches.append(nc)
-        return self._head(0, x), new_caches, pos + c
+        return self._head(0, x, pos)
 
     def prefill(self, tokens: jax.Array, caches, pos: jax.Array,
                 chunk: Optional[int] = None,
@@ -324,6 +424,9 @@ class AFDRuntime:
         ``decode_step`` on the dense path — every per-token arithmetic step
         is the same program evaluated batched.
 
+        ``caches`` is consumed: its arrays are donated and the list is
+        updated in place to the new caches.
+
         Returns (logits (B, S, V) f32, caches, pos + S).
         """
         if attn_impl is None and kops.default_impl() == "pallas":
@@ -332,8 +435,8 @@ class AFDRuntime:
         c = s if chunk is None else max(1, int(chunk))
         parts = []
         for off in range(0, s, c):
-            lg, caches, pos = self._prefill_block(
-                tokens[:, off:off + c], caches, pos, attn_impl)
+            lg, pos = self._prefill_block(tokens[:, off:off + c], caches, pos,
+                                          attn_impl)
             parts.append(lg)
         logits = parts[0] if len(parts) == 1 else jnp.concatenate(parts,
                                                                   axis=1)
@@ -391,19 +494,20 @@ def make_expert_ffn(cfg: ArchConfig, f_mesh: Mesh,
 
 
 def make_chunk_attention(cfg: ArchConfig, a_mesh: Mesh):
-    """The A role's flash-prefill chunk attention as one program over
-    ``a_mesh``: ``fn(attn_params, x, cache, pos) -> (out, new_cache)``.
+    """The A role's chunk ``afd.attn`` with the flash-prefill kernel as one
+    program over ``a_mesh``: ``fn(ln1, attn_params, x, cache, pos) ->
+    (x + attention, new_cache)``, the cache donated.
 
     A-role operands are replicated over the mesh. XLA cannot partition a
     Pallas call, even over replicated operands, so each A device runs the
-    kernel on its own copy inside a ``shard_map``.
+    norm, the kernel and the residual on its own copy inside a
+    ``shard_map``.
     """
     rep = P()
     return jax.jit(jax.shard_map(
-        lambda p, x, cache, pos: attn_mod.attention_prefill_cached(
-            p, cfg, x, cache, pos, impl="pallas"),
-        mesh=a_mesh, in_specs=(rep,) * 4, out_specs=(rep, rep),
-        check_vma=False))
+        functools.partial(_attn_chunk, cfg, "pallas"),
+        mesh=a_mesh, in_specs=(rep,) * 5, out_specs=(rep, rep),
+        check_vma=False), donate_argnums=(3,))
 
 
 def role_devices(devices: Sequence) -> Tuple[list, list]:

@@ -4,6 +4,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import repro.models.attention as attn_mod
 from repro.models import kvcache, layers, mamba2
@@ -138,6 +139,49 @@ def test_ring_cache_wraps_and_masks():
         vm = kvcache.valid_mask(cfg, 4, jnp.asarray([pos]))
         live = int(vm.sum())
         assert live == min(pos + 1, 4)
+
+
+def _kv_step(cfg, b, t, seed):
+    rng = np.random.default_rng(seed)
+    shape = (b, t, cfg.n_kv_heads, cfg.d_head)
+    cache = {n: jnp.asarray(rng.normal(size=shape), jnp.float32)
+             for n in ("k", "v")}
+    new = [jnp.asarray(rng.normal(size=(b, 1) + shape[2:]), jnp.float32)
+           for _ in range(2)]
+    return cache, new
+
+
+@pytest.mark.parametrize("window,positions", [
+    (None, [0, 5, 15, 7]), (4, [0, 3, 4, 9])])
+def test_write_kv_rows_matches_a_scatter(window, positions):
+    """The per-sequence update slices write what an independent scatter
+    writes, each row at its own position (a ring cache at pos mod window),
+    and leave every other slot as it was."""
+    cfg = _attn_cfg(sliding_window=window)
+    t = 4 if window else 16
+    cache, (k_new, v_new) = _kv_step(cfg, len(positions), t, 0)
+    got = kvcache.write_kv_rows(cfg, cache, k_new, v_new,
+                                jnp.asarray(positions, jnp.int32))
+    for name, new in (("k", k_new), ("v", v_new)):
+        want = np.array(cache[name])
+        for b, p in enumerate(positions):
+            want[b, p % t] = np.asarray(new)[b, 0]
+        np.testing.assert_array_equal(np.asarray(got[name]), want)
+
+
+def test_write_kv_rows_past_the_cache_stays_in_its_row():
+    """A position past a full cache lands, clamped, in its own row's last
+    slot; no other row and no earlier slot changes."""
+    cfg = _attn_cfg()
+    t = 8
+    cache, (k_new, v_new) = _kv_step(cfg, 3, t, 1)
+    got = kvcache.write_kv_rows(cfg, cache, k_new, v_new,
+                                jnp.asarray([2, t, t + 5], jnp.int32))
+    for name, new in (("k", k_new), ("v", v_new)):
+        want = np.array(cache[name])
+        want[0, 2] = np.asarray(new)[0, 0]
+        want[1:, t - 1] = np.asarray(new)[1:, 0]
+        np.testing.assert_array_equal(np.asarray(got[name]), want)
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,9 @@ from repro.kernels.flash_prefill import flash_prefill_pallas
 from repro.kernels.grouped_gemm import grouped_gemm_pallas
 from repro.kernels.splitkv_attention import splitkv_attention_pallas
 from repro.models import attention as attn_mod, kvcache
-from repro.parallel.afd import make_chunk_attention, make_expert_ffn
+from repro.models.layers import init_norm
+from repro.parallel.afd import (make_chunk_attention, make_expert_ffn,
+                                mix_attn)
 
 CFG = configs.get_config("granite-moe-1b-a400m")
 E, D, M, K = CFG.n_experts, CFG.d_model, CFG.moe_d_ff, CFG.top_k
@@ -170,10 +172,11 @@ def test_expert_ffn_is_expert_parallel(topo, no_persistent_cache,
 @pytest.mark.parametrize("n_a", [1, 2])
 def test_chunk_attention_on_a_role(topo, no_persistent_cache, monkeypatch,
                                    n_a):
-    """The A role's flash-prefill chunk attention over n_a described chips
-    holding replicated operands: the kernel compiles per device inside
-    the shard_map (an unwrapped Pallas call over a multi-device mesh is
-    refused as unpartitionable)."""
+    """The A role's flash-prefill chunk attention (norm, kernel, residual)
+    over n_a described chips holding replicated operands: the kernel
+    compiles per device inside the shard_map (an unwrapped Pallas call over
+    a multi-device mesh is refused as unpartitionable), and the cache is
+    donated, so its write aliases the input."""
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     mesh = Mesh(topo.devices[:n_a], ("model",))
 
@@ -187,8 +190,41 @@ def test_chunk_attention_on_a_role(topo, no_persistent_cache, monkeypatch,
         jax.random.PRNGKey(0), "attn", CFG))
     cache = jax.eval_shape(lambda: kvcache.init_layer_cache(CFG, spec, 2,
                                                             2048))
+    ln1 = jax.eval_shape(lambda: init_norm(jax.random.PRNGKey(0), "ln1",
+                                           CFG))
     hlo = make_chunk_attention(CFG, mesh).lower(
-        on(params), on(jax.ShapeDtypeStruct((2, 128, D), BF16)), on(cache),
-        on(jax.ShapeDtypeStruct((2,), jnp.int32))).compile().as_text()
+        on(ln1), on(params), on(jax.ShapeDtypeStruct((2, 128, D), BF16)),
+        on(cache), on(jax.ShapeDtypeStruct((2,), jnp.int32))
+    ).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert "all-gather" not in hlo
+    assert "input_output_alias" in hlo
+
+
+def test_decode_attention_writes_the_cache_in_place(chip):
+    """The A role's decode ``afd.attn`` program over a 16-slot, 2048-token
+    cache: the cache is donated and written in place. A head of 64 is laid
+    out with the positions minor, where a scatter would copy the whole
+    cache out of that layout and back; what may stay is the prefetch of
+    the cache into fast memory (``copy-start``)."""
+    b, t = 16, 2048
+    spec = CFG.layer_plan().flat()[0]
+
+    def on(tree):
+        return jax.tree_util.tree_map(lambda s: chip(s.shape, s.dtype), tree)
+
+    params = jax.eval_shape(lambda: attn_mod.init_attention(
+        jax.random.PRNGKey(0), "attn", CFG))
+    ln1 = jax.eval_shape(lambda: init_norm(jax.random.PRNGKey(0), "ln1",
+                                           CFG))
+    cache = jax.eval_shape(lambda: kvcache.init_layer_cache(CFG, spec, b, t))
+    hlo = mix_attn.lower(CFG, on(ln1), on(params), chip((b, 1, D), BF16),
+                         on(cache), chip((b,), jnp.int32)
+                         ).compile().as_text()
+    assert "input_output_alias" in hlo
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}\n")]
+    whole = f"bf16[{b},{t},{HKV},{DH}]"
+    copies = [ln for ln in entry.splitlines()
+              if f"= {whole}" in ln and " copy(" in ln]
+    assert not copies, copies
